@@ -9,7 +9,9 @@ architecture on the 2x16x16 multi-pod mesh:
   3. qdfedrw   -- gossip with 8-bit stochastically quantized payloads (Eq. 14).
 
 Runs repro.launch.dryrun in subprocesses (the 512-device placeholder must
-not leak into this process).
+not leak into this process). The dryrun CLI pins its process to the CPU
+backend, the only one with that placeholder, so a child never tries to open
+a chip that this parent process may hold.
 """
 import json
 import os

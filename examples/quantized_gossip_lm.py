@@ -17,6 +17,7 @@ from jax.sharding import NamedSharding
 from repro.dist.gossip import GossipConfig
 from repro.dist.sharding import batch_specs, named
 from repro.dist.steps import make_fed_train_step
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.config import ArchConfig
 
@@ -24,7 +25,7 @@ from repro.models.config import ArchConfig
 def main():
     cfg = ArchConfig(name="tiny-lm", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=256)
-    mesh = jax.make_mesh((4, 2, 1), ("pod", "data", "model"))
+    mesh = make_mesh((4, 2, 1), ("pod", "data", "model"))
     g = 4  # federated groups == pod axis
     for quant_bits, tag in [(32, "DFedRW"), (8, "QDFedRW-8b")]:
         gossip = GossipConfig(axis="pod", topology="ring", every=2,
@@ -55,11 +56,12 @@ def main():
                 key, sub = jax.random.split(key)
                 params, vel, loss = jitted(params, vel, batch, jnp.int32(step), sub)
                 if (step + 1) % 10 == 0:
-                    print(f"  [{tag}] step {step+1:3d} loss={float(loss):.4f}")
+                    print(f"  [{tag}] step {step+1:3d} "
+                          f"loss={np.mean(np.asarray(loss)):.4f}")
         # Group divergence after gossip: should be small (aggregated).
         leaf = jax.tree_util.tree_leaves(params)[0]
         spread = float(jnp.max(jnp.std(leaf.astype(jnp.float32), axis=0)))
-        print(f"  [{tag}] final loss={float(loss):.4f} inter-group param spread={spread:.5f}\n")
+        print(f"  [{tag}] final loss={np.mean(np.asarray(loss)):.4f} inter-group param spread={spread:.5f}\n")
 
 
 if __name__ == "__main__":

@@ -33,8 +33,9 @@ the parity tests in tests/test_flat_engine.py.
 vectorized + Pallas) or ``"reference"`` (the seed per-leaf/per-chain
 engine, kept as the numerical oracle and benchmark baseline). Both share the
 host-side planner, so seeded runs are comparable round by round. The flat
-round function donates the device matrix on accelerators and guards against
-shape-induced retraces (aggregation plans are padded to fixed shapes).
+round function donates the device matrix (the state passed to a round is
+consumed by it) and guards against shape-induced retraces (aggregation
+plans are padded to fixed shapes).
 
 The per-round inner loop is jitted once per (M, K, batch) shape; walk plans
 and data gathers are cheap host-side numpy.
@@ -179,7 +180,7 @@ class DFedRW:
         # have executed so the retrace warning stays meaningful.
         self._round_fns: dict[int, Any] = {}
         self._programs_run: set[int] = set()
-        self._get_round_fn(cfg.quant.bits)
+        self.round_program(cfg.quant.bits)
 
     # ------------------------------------------------------------------ init
     def init_state(self, key: jax.Array) -> DFedRWState:
@@ -226,9 +227,11 @@ class DFedRW:
         no RNG stream and no output bit."""
         self.obs = rec
 
-    def _get_round_fn(self, bits: int):
-        """The compiled round program for a wire bit-width (built on first
-        request; use prepare_bits to pre-build a controller's whole table)."""
+    def round_program(self, bits: int):
+        """The jitted round program for a wire bit-width (built on first
+        request; use prepare_bits to pre-build a controller's whole table).
+        ``round_program(b).lower(*round_inputs(...))`` gives its compiled
+        form without running it."""
         bits = validate_wire_bits(int(bits))
         fn = self._round_fns.get(bits)
         if fn is None:
@@ -245,7 +248,7 @@ class DFedRW:
         program object (tracing still happens on each program's first call —
         once per width, never again)."""
         for b in widths:
-            self._get_round_fn(b)
+            self.round_program(b)
 
     @property
     def prepared_bits(self) -> tuple[int, ...]:
@@ -270,11 +273,9 @@ class DFedRW:
 
         grad_fn = jax.vmap(jax.grad(loss_flat))
 
-        donate = () if jax.default_backend() == "cpu" else (0,)
-
-        @functools.partial(jax.jit, donate_argnums=donate)
+        @functools.partial(jax.jit, donate_argnums=(0,))
         def round_fn(
-            device_flat,              # (n, d_pad) f32 — donated off-CPU
+            device_flat,              # (n, d_pad) f32 — donated
             walk_devices,             # (M, K) int32
             walk_mask,                # (M, K) bool
             batch_idx,                # (M, K, B) int64 into global data
@@ -730,6 +731,15 @@ class DFedRW:
                 plan, bidx, agg = self._plan_round(state)
         return self.execute_round(state, plan, bidx, agg, key)
 
+    def round_inputs(self, state: DFedRWState, plan: WalkPlan,
+                     bidx: np.ndarray, agg: tuple, key: jax.Array) -> tuple:
+        """The round program's arguments for one planned round."""
+        agg_devices, agg_rows, agg_w = agg
+        return (state.device_params, jnp.asarray(plan.devices),
+                jnp.asarray(plan.mask), jnp.asarray(bidx),
+                jnp.asarray(agg_rows), jnp.asarray(agg_w),
+                jnp.asarray(agg_devices), jnp.int32(state.global_step), key)
+
     def execute_round(
         self,
         state: DFedRWState,
@@ -752,19 +762,10 @@ class DFedRW:
         obs = self.obs
         t_obs = obs.clock.now() if obs is not None else 0.0
         bits_eff = cfg.quant.bits if bits is None else int(bits)
-        round_fn = self._get_round_fn(bits_eff)
-        agg_devices, agg_rows, agg_w = agg
+        round_fn = self.round_program(bits_eff)
+        agg_devices = agg[0]
         new_params, loss, gamma_hat = round_fn(
-            state.device_params,
-            jnp.asarray(plan.devices),
-            jnp.asarray(plan.mask),
-            jnp.asarray(bidx),
-            jnp.asarray(agg_rows),
-            jnp.asarray(agg_w),
-            jnp.asarray(agg_devices),
-            jnp.int32(state.global_step),
-            key,
-        )
+            *self.round_inputs(state, plan, bidx, agg, key))
         self._programs_run.add(bits_eff)
         retraces = self.retrace_count
         if retraces > self._retraces_warned:

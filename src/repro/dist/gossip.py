@@ -26,7 +26,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quantization import QuantConfig, dequantize, quantize
@@ -142,7 +141,7 @@ def gossip_mix(tree: Any, specs: Any, mesh, cfg: GossipConfig,
     spec_leaves = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda s: isinstance(s, P)
     )
-    mixed = shard_map(
+    mixed = jax.shard_map(
         mix,
         mesh=mesh,
         in_specs=(P(),) + tuple(spec_leaves),
@@ -165,7 +164,7 @@ def walk_permute_batch(tree: Any, specs: Any, mesh, axis: str,
     spec_leaves = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda s: isinstance(s, P)
     )
-    moved = shard_map(
+    moved = jax.shard_map(
         hop,
         mesh=mesh,
         in_specs=tuple(spec_leaves),

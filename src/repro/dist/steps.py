@@ -131,7 +131,9 @@ def make_fed_train_step(cfg: ArchConfig, mesh, gossip: GossipConfig, *,
                         remat: bool = True, unroll: bool = False,
                         dtype=jnp.bfloat16, scheduled: bool = False):
     """The DFedRW pod deployment: step_fn(params, vel, batch, step, key)
-    -> (params, vel, mean_loss).
+    -> (params, vel, losses), with one loss per pod: the step has no
+    cross-pod reduction, so its only collectives are the gossip's permutes
+    (the caller averages the losses on the host).
 
     ``params``/``vel`` stack one model per pod (leading dim over
     ``gossip.axis``); ``batch`` leaves carry the matching leading group dim
@@ -158,7 +160,7 @@ def make_fed_train_step(cfg: ArchConfig, mesh, gossip: GossipConfig, *,
         ))(params, batch)
         lr = decreasing_lr(step + 1, r=lr_r)
         params, vel = momentum_sgd(params, vel, grads, lr, beta)
-        return params, vel, jnp.mean(losses)
+        return params, vel, losses
 
     if scheduled:
         def step_fn(params, vel, batch, step, do_gossip, key):

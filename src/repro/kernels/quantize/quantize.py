@@ -15,6 +15,8 @@ layout: input reshaped to (rows, 128) lanes, row-tiles of 512 in VMEM
 
 The scalar ||w|| is computed by XLA (a single fused reduction) and passed
 via SMEM; quantization grid s and bit-width are compile-time constants.
+The segment-wise kernels take their per-row scale and norm as (tile, 1)
+VMEM blocks, and the counter-RNG seed words as a whole-array SMEM operand.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "quantize_kernel_call",
@@ -36,6 +39,8 @@ __all__ = [
 
 ROW_TILE = 512
 LANES = 128
+
+_SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _quantize_kernel(w_ref, u_ref, norm_ref, out_ref, *, s: float, levels: int):
@@ -70,7 +75,7 @@ def quantize_kernel_call(w2d: jax.Array, u2d: jax.Array, norm: jax.Array,
         in_specs=[
             pl.BlockSpec((ROW_TILE, LANES), lambda i: (i, 0)),
             pl.BlockSpec((ROW_TILE, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pl.ANY),
+            _SMEM_WHOLE,
         ],
         out_specs=pl.BlockSpec((ROW_TILE, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
@@ -150,7 +155,10 @@ def _counter_uniforms(tile: int, pid, seed_ref):
     x = x * jnp.uint32(0x27D4EB2F)
     x = x ^ (x >> jnp.uint32(15))
     # 24-bit mantissa resolution, matching jax.random.uniform's float32.
-    return (x >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # The value is below 2^24, so the cast through int32 (Mosaic has no
+    # uint32 -> float32 conversion) is exact.
+    return ((x >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def _qdq_delta_rows_rng_kernel(w_ref, base_ref, seed_ref, s_ref, norm_ref,
@@ -211,8 +219,8 @@ def quantize_rows_kernel_call(w2d: jax.Array, u2d: jax.Array, s_rows: jax.Array,
         in_specs=[
             pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
             pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=pl.ANY),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=pl.ANY),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
@@ -246,7 +254,7 @@ def qdq_rows_kernel_call(w2d: jax.Array, u2d: jax.Array | None,
     levels = (1 << (bits - 1)) - 1
     grid = (rows // tile,)
     row_block = pl.BlockSpec((tile, LANES), lambda i: (i, 0))
-    scalar_block = pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=pl.ANY)
+    scalar_block = pl.BlockSpec((tile, 1), lambda i: (i, 0))
     if u2d is not None:
         rng_operand = u2d
         rng_spec = row_block
@@ -254,7 +262,7 @@ def qdq_rows_kernel_call(w2d: jax.Array, u2d: jax.Array | None,
         extra = {}
     else:
         rng_operand = seed.reshape(1, 2).astype(jnp.uint32)
-        rng_spec = pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pl.ANY)
+        rng_spec = _SMEM_WHOLE
         kernels = (_qdq_rows_rng_kernel, _qdq_delta_rows_rng_kernel)
         extra = {"tile": tile}
     if base2d is None:
@@ -288,8 +296,8 @@ def dequantize_rows_kernel_call(q2d: jax.Array, s_rows: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=pl.ANY),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=pl.ANY),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
@@ -307,7 +315,7 @@ def dequantize_kernel_call(q2d: jax.Array, norm: jax.Array, *, s: float,
         grid=grid,
         in_specs=[
             pl.BlockSpec((ROW_TILE, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pl.ANY),
+            _SMEM_WHOLE,
         ],
         out_specs=pl.BlockSpec((ROW_TILE, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),

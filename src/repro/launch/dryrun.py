@@ -1,11 +1,13 @@
 import os
 if __name__ == "__main__":
     # CLI entry (python -m repro.launch.dryrun): the production meshes need
-    # 512 virtual host devices, and the flag MUST be set before any other
-    # import (jax locks the device count at first init). Plain imports of
-    # this module (tests/benchmarks using the pure helpers below) must NOT
-    # mutate the process environment or device count.
+    # 512 virtual host devices, which only the CPU backend provides, and
+    # both settings MUST be made before any other import (jax locks the
+    # backend and device count at first init). Plain imports of this module
+    # (tests/benchmarks using the pure helpers below) must NOT mutate the
+    # process environment or device count.
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, printing memory and cost analysis (the roofline
@@ -372,7 +374,9 @@ def main(argv=None):
     ap.add_argument("--opt", action="store_true", help="beyond-paper optimized variant")
     ap.add_argument("--json", type=str, default="")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     results = []
     combos = (
         [(a, s) for a in ARCH_IDS for s in SHAPES]

@@ -6,15 +6,23 @@ axis is where the DFedRW gossip technique operates (each pod = one
 federated client group); "data" is batch/fsdp parallelism; "model" is
 tensor/expert parallelism.
 
+Every mesh of the repo is built by ``make_mesh`` here. Its axes are
+``AxisType.Auto``: the step builders are written for GSPMD propagation
+(``PartitionSpec`` placements plus ``with_sharding_constraint`` under the
+mesh context), while ``jax.make_mesh`` defaults every axis to ``Explicit``
+sharding-in-types.
+
 NOTE: defined as functions (never module-level constants) so importing this
 module never touches jax device state.
 """
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_pod_mesh",
-           "make_metal_mesh", "HW"]
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh",
+           "make_pod_mesh", "make_chains_mesh", "make_metal_mesh", "HW"]
 
 
 class HW:
@@ -27,10 +35,20 @@ class HW:
     HBM_BYTES = 16e9               # v5e HBM capacity
 
 
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """A mesh of GSPMD (``Auto``) axes over ``devices`` in the given order,
+    or over ``jax.make_mesh``'s choice of the process's devices."""
+    axis_types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
+    return Mesh(np.asarray(devices).reshape(shape), tuple(axes),
+                axis_types=axis_types)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -38,7 +56,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     model = max(1, min(model, n))
     data = max(1, min(data, n // model))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_metal_mesh(chains: int = 0, *, coordinator: str | None = None,
@@ -58,9 +76,6 @@ def make_metal_mesh(chains: int = 0, *, coordinator: str | None = None,
     them — no padding is ever needed), plus the process/device census the
     launcher logs.
     """
-    import numpy as np
-    from jax.sharding import Mesh
-
     if num_processes > 1:
         if coordinator is None:
             raise ValueError("multi-process bring-up needs a coordinator "
@@ -69,19 +84,13 @@ def make_metal_mesh(chains: int = 0, *, coordinator: str | None = None,
                                    num_processes=num_processes,
                                    process_id=process_id)
     devs = jax.local_devices()
-    axis = len(devs)
-    if chains:
-        axis = 1
-        for a in range(1, min(len(devs), chains) + 1):
-            if chains % a == 0:
-                axis = a
-    mesh = Mesh(np.array(devs[:axis]), ("chains",))
+    mesh = make_chains_mesh(devs, chains or len(devs))
     info = {
         "process_id": jax.process_index(),
         "process_count": jax.process_count(),
         "local_devices": len(devs),
         "global_devices": jax.device_count(),
-        "mesh_axis": axis,
+        "mesh_axis": mesh.size,
     }
     return mesh, info
 
@@ -94,4 +103,12 @@ def make_pod_mesh(pods: int = 0):
     n = len(jax.devices())
     pods = pods or n
     assert n % pods == 0, f"{n} devices not divisible into {pods} pods"
-    return jax.make_mesh((pods, n // pods, 1), ("pod", "data", "model"))
+    return make_mesh((pods, n // pods, 1), ("pod", "data", "model"))
+
+
+def make_chains_mesh(devices, chains: int) -> Mesh:
+    """1-axis ``("chains",)`` mesh over the largest prefix of ``devices``
+    whose length divides ``chains``, so no chain row is ever padded."""
+    axis = max(a for a in range(1, min(len(devices), max(chains, 1)) + 1)
+               if chains % a == 0)
+    return make_mesh((axis,), ("chains",), devices[:axis])

@@ -135,10 +135,14 @@ def _free_port() -> int:
 def _spawn_workers(args: argparse.Namespace) -> int:
     """Parent path of ``--processes N``: pick coordinator/exchange ports,
     spawn N worker copies of this CLI (rank 0 carries --check/--obs), and
-    fail if any worker fails."""
+    fail if any worker fails. The workers run on the CPU backend: this is a
+    conformance harness, and on an accelerator host every worker would
+    otherwise try to open every local chip."""
     coord_port, exch_port = _free_port(), _free_port()
     procs = []
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    print(f"replay: {args.processes} worker processes on the CPU backend",
+          flush=True)
     if args.host_devices:
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices}")
@@ -215,10 +219,12 @@ def main(argv=None) -> int:
     import numpy as np
 
     from repro.core.dfedrw import DFedRW
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_metal_mesh
     from repro.sim import FaultInjector, MetalReplay, SimTrace, \
         build_scenario, conformance_diff
 
+    enable_compile_cache()
     trace = SimTrace.load(args.trace)
     h = trace.header
     if not {"scenario", "build_seed", "key_seed"} <= set(h):

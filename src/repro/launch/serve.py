@@ -80,7 +80,21 @@ def sequential_reference(cfg, params, req, max_len: int, step=None):
     return out
 
 
-def main(argv=None) -> None:
+def verify_sequential(cfg, params, results, max_len: int) -> list[int]:
+    """``--verify``: replay every finished request through the sequential
+    decode path; return the ids whose tokens differ (temp-0 engines must
+    return none)."""
+    import jax
+
+    from repro.models import transformer as T
+
+    step = jax.jit(lambda p, c, t: T.decode_step(cfg, p, c, t))
+    return [st.request.rid for st in results
+            if st.generated != sequential_reference(cfg, params, st.request,
+                                                    max_len, step)]
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__,
                                  formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", required=True)
@@ -118,16 +132,22 @@ def main(argv=None) -> None:
                     help="with --obs: record per-request causal span trees "
                          "(admit/prefill_chunk/decode tspan events; export: "
                          "python tools/obs_trace_export.py <obs.jsonl>)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> None:
+    args = parse_args(argv)
 
     import jax
     import jax.numpy as jnp
 
     from repro.configs import get_arch, get_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import transformer as T
     from repro.serve import EngineConfig, ServeEngine
 
+    enable_compile_cache()
     smoke = not args.full
     cfg = get_smoke(args.arch) if smoke else get_arch(args.arch)
     dtype = jnp.float32 if smoke else jnp.bfloat16
@@ -170,12 +190,7 @@ def main(argv=None) -> None:
     if args.verify:
         if args.temperature > 0:
             raise SystemExit("--verify requires --temperature 0")
-        step = jax.jit(lambda p, c, t: T.decode_step(cfg, p, c, t))
-        bad = []
-        for st in results:
-            ref = sequential_reference(cfg, params, st.request, max_len, step)
-            if st.generated != ref:
-                bad.append(st.request.rid)
+        bad = verify_sequential(cfg, params, results, max_len)
         if bad:
             raise SystemExit(f"VERIFY FAILED: engine != sequential decode for rids {bad}")
         print(f"verify: all {len(results)} requests bit-identical to the "

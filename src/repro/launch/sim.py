@@ -86,12 +86,14 @@ def main(argv=None) -> None:
                          "python tools/obs_trace_export.py <obs.jsonl>")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.sim import build_scenario, list_scenarios
 
     if args.list:
         for name, desc in sorted(list_scenarios().items()):
             print(f"{name:20s} {desc}")
         return
+    enable_compile_cache()
 
     import jax
 

@@ -91,49 +91,82 @@ def protocol_main(args) -> None:
         print(f"checkpointed to {args.checkpoint_dir}")
 
 
-def pod_main(args) -> None:
+def make_batch(cfg, rng: np.random.Generator, batch: int, seq: int,
+               lead: tuple = ()) -> dict:
+    """Uniform random next-token batch (host numpy): tokens, labels and,
+    for archs with a frontend, random frontend embeddings."""
+    toks = rng.integers(0, cfg.vocab, size=(*lead, batch, seq + 1))
+    out = {"tokens": toks[..., :-1].astype(np.int32),
+           "labels": toks[..., 1:].astype(np.int32)}
+    if cfg.frontend != "none":
+        out["embeds"] = rng.normal(
+            size=(*lead, batch, cfg.frontend_tokens, cfg.d_model)
+        ).astype(np.float32)
+    return out
+
+
+def pod_main(args, cfg=None) -> list[float]:
+    """``pod`` mode; ``cfg`` overrides the ``--arch`` lookup (a depth-cut
+    config, say). Returns the per-step losses."""
     import jax
     import jax.numpy as jnp
 
     from repro.configs import get_arch, get_smoke
     from repro.models import transformer as T
 
-    cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
-    key = jax.random.PRNGKey(0)
-    rng = np.random.default_rng(0)
-    b, s = args.batch, args.seq
-
-    def make_batch(lead=()):
-        toks = rng.integers(0, cfg.vocab, size=(*lead, b, s + 1))
-        batch = {"tokens": jnp.asarray(toks[..., :-1], jnp.int32),
-                 "labels": jnp.asarray(toks[..., 1:], jnp.int32)}
-        if cfg.frontend != "none":
-            batch["embeds"] = jnp.asarray(rng.normal(
-                size=(*lead, b, cfg.frontend_tokens, cfg.d_model)), jnp.float32)
-        return batch
-
+    if cfg is None:
+        cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
+    key = jax.random.PRNGKey(args.seed)
+    rng = np.random.default_rng(args.seed)
     if args.fed:
-        fed_pod_main(args, cfg, key, make_batch)
-        return
+        return fed_pod_main(args, cfg, key, rng)
 
+    from repro.dist.sharding import named
     from repro.dist.steps import make_train_step
     from repro.launch.mesh import make_host_mesh
 
     mesh = make_host_mesh(data=len(jax.devices()))
 
     step_fn, p_specs = make_train_step(cfg, mesh, lr_r=args.lr_r)
-    params = T.init_params(cfg, key, jnp.float32)
+    params = jax.jit(lambda k: T.init_params(cfg, k, jnp.float32),
+                     out_shardings=named(p_specs, mesh))(key)
     vel = jax.tree_util.tree_map(jnp.zeros_like, params)
-    jitted = jax.jit(step_fn)
+    jitted = jax.jit(step_fn, donate_argnums=(0, 1))
+    losses = []
     with mesh:
         for step in range(args.steps):
+            batch = make_batch(cfg, rng, args.batch, args.seq)
             t0 = time.time()
-            params, vel, loss = jitted(params, vel, make_batch(), jnp.int32(step))
-            print(f"step {step:3d} loss={float(loss):.4f} ({time.time()-t0:.2f}s)")
+            params, vel, loss = jitted(params, vel, batch, jnp.int32(step))
+            losses.append(float(loss))
+            print(f"step {step:3d} loss={losses[-1]:.4f} ({time.time()-t0:.2f}s)")
     print("done")
+    return losses
 
 
-def fed_pod_main(args, cfg, key, make_batch) -> None:
+def init_fed_state(cfg, mesh, p_specs, key):
+    """(params, vel) of the pod deployment: every pod starts from the same
+    float32 model with zero momentum. Built in place by one program, so each
+    pod's replica is created on its own devices."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.dist.sharding import named
+    from repro.models import transformer as T
+
+    g = dict(mesh.shape)["pod"]
+
+    def init(k):
+        params = jax.tree_util.tree_map(
+            lambda l: jnp.broadcast_to(l, (g, *l.shape)),
+            T.init_params(cfg, k, jnp.float32))
+        return params, jax.tree_util.tree_map(jnp.zeros_like, params)
+
+    shard = named(p_specs, mesh)
+    return jax.jit(init, out_shardings=(shard, shard))(key)
+
+
+def fed_pod_main(args, cfg, key, rng) -> list[float]:
     """pod --fed: the decomposed DFedRW deployment on the host's devices."""
     import jax
     import jax.numpy as jnp
@@ -142,7 +175,6 @@ def fed_pod_main(args, cfg, key, make_batch) -> None:
     from repro.dist.sharding import batch_specs, named
     from repro.dist.steps import make_fed_train_step
     from repro.launch.mesh import make_pod_mesh
-    from repro.models import transformer as T
 
     mesh = make_pod_mesh(args.pods)
     g = dict(mesh.shape)["pod"]
@@ -150,31 +182,31 @@ def fed_pod_main(args, cfg, key, make_batch) -> None:
                           every=args.gossip_every, quant_bits=args.bits)
     step_fn, p_specs, _ = make_fed_train_step(cfg, mesh, gossip, lr_r=args.lr_r,
                                               remat=False, dtype=jnp.float32)
-    base = T.init_params(cfg, key, jnp.float32)
-    params = jax.tree_util.tree_map(
-        lambda l: jnp.broadcast_to(l, (g, *l.shape)).copy(), base)
-    params = jax.device_put(params, named(p_specs, mesh))
-    vel = jax.tree_util.tree_map(jnp.zeros_like, params)
-    jitted = jax.jit(step_fn)
+    params, vel = init_fed_state(cfg, mesh, p_specs, key)
+    jitted = jax.jit(step_fn, donate_argnums=(0, 1))
     print(f"fed pod mode: {g} pods x data={dict(mesh.shape)['data']} "
           f"topology={gossip.topology} every={gossip.every} bits={gossip.quant_bits}")
     b_shard = None  # batch shapes are constant: compute shardings once
+    losses = []
     with mesh:
         for step in range(args.steps):
-            batch = make_batch(lead=(g,))
+            batch = make_batch(cfg, rng, args.batch, args.seq, lead=(g,))
             if b_shard is None:
                 b_shard = named(batch_specs(batch, mesh, fed_axis="pod"), mesh)
             batch = jax.device_put(batch, b_shard)
             key, sub = jax.random.split(key)
             t0 = time.time()
-            params, vel, loss = jitted(params, vel, batch, jnp.int32(step), sub)
-            print(f"step {step:3d} loss={float(loss):.4f} ({time.time()-t0:.2f}s)")
+            params, vel, pod_losses = jitted(params, vel, batch,
+                                             jnp.int32(step), sub)
+            losses.append(float(np.mean(np.asarray(pod_losses))))
+            print(f"step {step:3d} loss={losses[-1]:.4f} ({time.time()-t0:.2f}s)")
     leaf = jax.tree_util.tree_leaves(params)[0]
     spread = float(jnp.max(jnp.std(leaf.astype(jnp.float32), axis=0)))
     print(f"done (inter-pod param spread={spread:.5f})")
+    return losses
 
 
-def main(argv=None) -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="mode", required=True)
     p = sub.add_parser("protocol")
@@ -213,7 +245,16 @@ def main(argv=None) -> None:
                    help="gossip payload quantization bits (<32 = QDFedRW)")
     q.add_argument("--topology", default="ring",
                    choices=["ring", "expander", "all"])
-    args = ap.parse_args(argv)
+    q.add_argument("--seed", type=int, default=0,
+                   help="seed of the initial weights and the token batches")
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = parse_args(argv)
+    enable_compile_cache()
     (protocol_main if args.mode == "protocol" else pod_main)(args)
 
 

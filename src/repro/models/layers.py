@@ -489,7 +489,8 @@ def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, jax.Ar
     keep = pos < cap
     gate_vals = gate_vals * keep
 
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, cap), cap, dtype=x.dtype)  # (b,l,k,cap)
+    slot = jnp.where(keep, pos, cap).astype(jnp.int32)          # exact: small ints
+    pos_oh = jax.nn.one_hot(slot, cap, dtype=x.dtype)            # (b,l,k,cap)
     disp = jnp.einsum("blke,blkc->blec", sel.astype(x.dtype), pos_oh)       # (b,l,e,cap)
     comb = jnp.einsum("blk,blke,blkc->blec", gate_vals.astype(x.dtype),
                       sel.astype(x.dtype), pos_oh)

@@ -417,22 +417,15 @@ class Recorder:
 
 @contextlib.contextmanager
 def jax_profile(logdir: str | None) -> Iterator[None]:
-    """Optional ``jax.profiler`` session around a block: no-op when ``logdir``
-    is falsy or the profiler is unavailable (e.g. interpret-mode CPU boxes
-    without a TensorBoard plugin)."""
+    """``jax.profiler`` session around a block, writing its trace under
+    ``logdir``; a no-op when ``logdir`` is falsy. A trace that cannot be
+    started or stopped raises."""
     if not logdir:
         yield
         return
-    try:
-        import jax
-        jax.profiler.start_trace(logdir)
-    except Exception:
-        yield
-        return
+    import jax
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()

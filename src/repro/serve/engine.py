@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.dist.sharding import cache_specs, named, param_specs, serve_arg_specs  # noqa: F401
 from repro.dist.steps import make_prefill_step, make_serve_step
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.config import ArchConfig
 from repro.serve.metrics import EngineMetrics, RequestMetrics
@@ -122,7 +123,7 @@ class ServeEngine:
         self.ring_size = ring
         self.chunk = min(self.engine.chunk, ring)
         self.min_prefill_rows = self.engine.min_prefill_rows  # 0 = auto
-        self.mesh = mesh if mesh is not None else jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh if mesh is not None else make_mesh((1, 1), ("data", "model"))
 
         serve_fn, p_specs = make_serve_step(cfg, self.mesh, slots=True)
         prefill_fn, _ = make_prefill_step(cfg, self.mesh)

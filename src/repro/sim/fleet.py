@@ -28,12 +28,14 @@ min_transfer_time``: starting from the earliest pending instant ``b0``,
 each chain can emit at most ONE cross-device send before ``b0 + delta``
 (a send's arrival costs >= min_transfer, the next local step >= min_step),
 so sweeping ``[b0, b0+delta)`` to quiescence collects every send of the
-bucket before any is admitted. Sends are admitted in ``(t_ready, chain)``
-order — for every lockstep parity scenario this equals the heap's
-``(time, seq)`` order, and it is the fleet's *deterministic tie contract*
-in general (two sends from one sender at the exact same instant with
-divergent histories may order differently than the heap's push sequence;
-see docs/SIMULATOR.md). Per-sender FIFO recursion
+bucket before any is admitted. Sends are admitted in the heap's
+``(time, seq)`` order: by ready instant, and one sender's sends ready at
+the same instant (chains released together when a churned device comes
+back) by their event ancestry, which is what the heap's push sequence
+encodes — each event is pushed while its chain's previous event is
+processed, so ``seq`` order is the order of the pushers' ``(time, seq)``,
+down to the slot order of the window's initial pushes. Per-sender FIFO
+recursion
 ``start_i = max(ready_i, done_{i-1})`` is evaluated sequentially inside
 each same-sender group (and by a bit-exact prepended-base cumsum for
 same-instant aggregation bursts), reproducing ``UplinkQueue.enqueue``'s
@@ -132,6 +134,12 @@ class FleetDFedRW(AsyncDFedRW):
         self._t_arr = np.full((m, k), np.nan)
         self._t_up = np.full((m, k), np.nan)
         self._t_send = np.full((m, k), np.nan)
+        # each chain's processed event instants (<= 3 per step: arrival,
+        # churn-wait end, step done) and the instant its first event was
+        # pushed: the heap's push order, for same-instant send ties
+        self._f_hist = np.full((m, 3 * k + 1), np.nan)
+        self._f_nev = np.zeros(m, dtype=np.int32)
+        self._f_root = np.zeros(m)
 
     def _q_reset(self) -> None:
         """Reset uplink busy/stats state (the array twin of
@@ -203,6 +211,8 @@ class FleetDFedRW(AsyncDFedRW):
             self._t_arr[free] = np.nan
             self._t_up[free] = np.nan
             self._t_send[free] = np.nan
+            self._f_nev[free] = 0
+            self._f_root[free] = t0
             # same ascending-slot uid order as the heap's _fill_slots
             self._chain_uid[free] = self._uid_next + np.arange(free.size)
             self._uid_next += int(free.size)
@@ -248,8 +258,14 @@ class FleetDFedRW(AsyncDFedRW):
                 continue
             return total
 
+    def _record(self, idx: np.ndarray, t: np.ndarray) -> None:
+        if self._queue_on:
+            self._f_hist[idx, self._f_nev[idx]] = t
+            self._f_nev[idx] += 1
+
     def _process_hops(self, idx: np.ndarray) -> None:
         t = self._f_time[idx]
+        self._record(idx, t)
         steps = self._f_step[idx]
         devs = self._f_dev[idx, steps].astype(np.int64)
         self._now = max(self._now, float(t.max()))
@@ -282,6 +298,7 @@ class FleetDFedRW(AsyncDFedRW):
 
     def _process_sgds(self, idx: np.ndarray) -> None:
         t = self._f_time[idx]
+        self._record(idx, t)
         k = self._f_step[idx]
         self._now = max(self._now, float(t.max()))
         self._f_kdone[idx] = k + 1
@@ -381,8 +398,9 @@ class FleetDFedRW(AsyncDFedRW):
             return
         idx = np.nonzero(sel)[0]
         t_ready = self._f_time[idx]
-        order = np.lexsort((idx, t_ready))     # (t_ready, chain): the fleet's
-        idx, t_ready = idx[order], t_ready[order]  # deterministic tie contract
+        order = np.lexsort((idx, t_ready))
+        idx, t_ready = idx[order], t_ready[order]
+        idx = self._heap_order_ties(idx, t_ready)
         step = self._f_step[idx]
         src = self._f_dev[idx, step - 1].astype(np.int64)
         dst = self._f_dev[idx, step].astype(np.int64)
@@ -394,6 +412,31 @@ class FleetDFedRW(AsyncDFedRW):
             self._t_send[idx, step] = t_start
         self._f_kind[idx] = _HOP
         self._f_time[idx] = t_done
+
+    def _heap_order_ties(self, idx: np.ndarray, t_ready: np.ndarray) -> np.ndarray:
+        """Within each group of one sender's sends ready at the same instant,
+        order the chains as the heap pops their step-done events: by the
+        instants of their earlier events, latest first (each event was
+        pushed while processing the one before it), then by when and in
+        which slot order the chain's first event was pushed."""
+        src = self._f_dev[idx, self._f_step[idx] - 1]
+        by = np.lexsort((src, t_ready))
+        tie = (t_ready[by][1:] == t_ready[by][:-1]) & (src[by][1:] == src[by][:-1])
+        if not tie.any():
+            return idx
+
+        def key(c):
+            hist = self._f_hist[c, :self._f_nev[c]][::-1]
+            return tuple((float(t), 1) for t in hist) + (
+                (float(self._f_root[c]), 0), int(c))
+
+        idx = idx.copy()
+        starts = np.nonzero(np.r_[True, ~tie])[0]
+        for lo, hi in zip(starts, np.r_[starts[1:], by.size]):
+            if hi - lo > 1:
+                pos = by[lo:hi]               # ascending admission positions
+                idx[pos] = sorted(idx[pos], key=key)
+        return idx
 
     # ----------------------------------------------------------- aggregation
     def _agg_latency(self, agg: tuple, n: int, t_trigger: float) -> float:
@@ -512,7 +555,8 @@ class FleetDFedRW(AsyncDFedRW):
         stash = (self._f_dev, self._f_bidx, self._f_ts, self._f_km,
                  self._f_kdone, self._f_wstart, self._f_killed, self._f_occ,
                  self._f_kind, self._f_step, self._f_time,
-                 self._t_arr, self._t_up, self._t_send, self._now)
+                 self._t_arr, self._t_up, self._t_send, self._f_hist,
+                 self._f_nev, self._f_root, self._now)
         self._alloc_chains(m, k, 0)
         self._q_reset()
         self._now = t0
@@ -522,6 +566,7 @@ class FleetDFedRW(AsyncDFedRW):
         started = plan.k_m > 0
         self._f_kind[:] = np.where(started, _HOP, _NONE).astype(np.int8)
         self._f_time[:] = np.where(started, t0, np.inf)
+        self._f_root[:] = t0
         events, host_loop_s = self._advance_window(deadline)
         k_done = self._f_kdone.copy()
         ts = self._f_ts.copy()
@@ -529,7 +574,8 @@ class FleetDFedRW(AsyncDFedRW):
         (self._f_dev, self._f_bidx, self._f_ts, self._f_km, self._f_kdone,
          self._f_wstart, self._f_killed, self._f_occ, self._f_kind,
          self._f_step, self._f_time,
-         self._t_arr, self._t_up, self._t_send, self._now) = stash
+         self._t_arr, self._t_up, self._t_send, self._f_hist,
+         self._f_nev, self._f_root, self._now) = stash
         return k_done, ts, killed, events, host_loop_s
 
     # ------------------------------------------------------------- tracing

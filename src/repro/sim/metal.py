@@ -262,15 +262,12 @@ class MetalReplay:
 
     # ------------------------------------------------------------ programs
     def _local_mesh(self, m_local: int):
-        from jax.sharding import Mesh
+        from repro.launch.mesh import make_chains_mesh
         devs = self._devices if self._devices is not None \
             else jax.local_devices()
-        axis = 1
-        for a in range(1, min(len(devs), max(m_local, 1)) + 1):
-            if m_local % a == 0:
-                axis = a
-        self._mesh_axis_used = max(self._mesh_axis_used, axis)
-        return Mesh(np.array(devs[:axis]), ("chains",))
+        mesh = make_chains_mesh(devs, m_local)
+        self._mesh_axis_used = max(self._mesh_axis_used, mesh.size)
+        return mesh
 
     def _walk_fn(self, bits: int, m_local: int):
         """Compiled walk program for (wire width, shard chain count): scans
@@ -280,7 +277,6 @@ class MetalReplay:
         fn = self._walk_fns.get((bits, m_local))
         if fn is not None:
             return fn
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         engine = self.engine
@@ -329,12 +325,12 @@ class MetalReplay:
             return traj, grad_sq                       # (K, mb, d) / (K, mb)
 
         if sharded:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(), P("chains"), P("chains"), P("chains"),
                           P(), P()),
                 out_specs=(P(None, "chains"), P(None, "chains")),
-                check_rep=False))
+                check_vma=False))
         else:
             fn = jax.jit(body)
         self._walk_fns[(bits, m_local)] = fn

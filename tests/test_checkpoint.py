@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
+from repro.launch.mesh import make_mesh
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -84,7 +85,7 @@ def test_per_pod_stacked_fed_state_roundtrip(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # restored stacks place onto the fed-axis shardings (pod axis size 1
     # on this host; the specs are the same ones a real pod mesh uses)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     placed_p = jax.device_put(back["params"],
                               named(param_specs(base, mesh, fed_axis="pod"), mesh))
     placed_v = jax.device_put(back["vel"],
@@ -110,7 +111,8 @@ _FED_RESTORE_CONTINUE = textwrap.dedent("""
 
     cfg = ArchConfig(name="tiny", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=128)
-    mesh = jax.make_mesh((4, 2, 1), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2, 1), ("pod", "data", "model"))
     gossip = GossipConfig(axis="pod", topology="ring", every=2)
     step_fn, p_specs, _ = make_fed_train_step(cfg, mesh, gossip, remat=False,
                                               dtype=jnp.float32)

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models.config import ArchConfig, MoEConfig
 from repro.models import transformer as T
 
@@ -23,7 +24,7 @@ TINY = ArchConfig(name="tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
 def test_train_step_learns_single_device():
     from repro.dist.steps import make_train_step
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     step_fn, _ = make_train_step(TINY, mesh, lr_r=2.0, remat=False)
     params = T.init_params(TINY, jax.random.PRNGKey(0), jnp.float32)
     vel = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -54,11 +55,7 @@ def test_opt_specs_shard_where_params_replicate():
     from repro.dist.steps import opt_specs
     from repro.dist.sharding import param_specs
 
-    try:
-        mesh = jax.sharding.AbstractMesh((1, 2, 1), ("pod", "data", "model"))
-    except TypeError:
-        mesh = jax.sharding.AbstractMesh(
-            (("pod", 1), ("data", 2), ("model", 1)))
+    mesh = jax.sharding.AbstractMesh((1, 2, 1), ("pod", "data", "model"))
     params = T.abstract_params(TINY, jnp.float32)
     p_specs = param_specs(params, mesh)
     o_specs = opt_specs(params, mesh)
@@ -91,7 +88,7 @@ def test_opt_specs_state_learns_single_device():
     from repro.dist.sharding import named
     from repro.dist.steps import make_train_step, opt_specs
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     step_fn, p_specs = make_train_step(TINY, mesh, lr_r=2.0, remat=False)
     params = T.init_params(TINY, jax.random.PRNGKey(0), jnp.float32)
     vel = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -148,7 +145,7 @@ def test_fed_train_step_scheduled_matches_static():
     from repro.dist.gossip import GossipConfig
     from repro.dist.steps import make_fed_train_step
 
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     gossip = GossipConfig(axis="pod", topology="ring", every=2)
     static_fn, _, _ = make_fed_train_step(
         TINY, mesh, gossip, lr_r=2.0, remat=False, dtype=jnp.float32)
@@ -204,7 +201,8 @@ _SCHEDULED_FED_STEP = textwrap.dedent("""
 
     cfg = ArchConfig(name="tiny", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=128)
-    mesh = jax.make_mesh((4, 2, 1), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2, 1), ("pod", "data", "model"))
     gossip = GossipConfig(axis="pod", topology="ring", every=2)
     static_fn, p_specs, _ = make_fed_train_step(
         cfg, mesh, gossip, lr_r=2.0, remat=False, dtype=jnp.float32)
@@ -273,7 +271,8 @@ _GOSSIP_STEP = textwrap.dedent("""
 
     cfg = ArchConfig(name="tiny", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=128)
-    mesh = jax.make_mesh((4, 2, 1), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2, 1), ("pod", "data", "model"))
     gossip = GossipConfig(axis="pod", topology="ring")
     gstep, p_specs, fed_abs = make_gossip_step(cfg, mesh, gossip)
 
@@ -321,7 +320,8 @@ _OPT_SPECS_STEP = textwrap.dedent("""
 
     cfg = ArchConfig(name="tiny", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=128)
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8, 1), ("data", "model"))
     step_fn, p_specs = make_train_step(cfg, mesh, lr_r=2.0, remat=False)
     o_specs = opt_specs(T.abstract_params(cfg), mesh)
     # the upgrade path must actually fire on a size-8 data axis: at least

@@ -34,7 +34,8 @@ _CORRECTION = textwrap.dedent("""
     from repro.models.config import ArchConfig
 
     D.SHAPES["tiny_train"] = dict(kind="train", seq_len=128, global_batch=16)
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = ArchConfig(name="tiny", n_layers=6, d_model=128, n_heads=4,
                      n_kv_heads=2, d_ff=256, vocab=512)
 

@@ -43,7 +43,9 @@ def _run_pair(data, topo, model, cfg, rounds=3):
         key, sub = jax.random.split(key)
         sr, mr = ref.run_round(sr, sub)
         sf, mf = fla.run_round(sf, sub)
-        out.append((sr, mr, sf, mf))
+        # the next flat round consumes (donates) sf's device matrix
+        snap = dataclasses.replace(sf, device_params=np.asarray(sf.device_params))
+        out.append((sr, mr, snap, mf))
     return ref, fla, out
 
 

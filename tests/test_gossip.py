@@ -43,7 +43,8 @@ _SUBPROC = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.dist.gossip import GossipConfig, gossip_mix, walk_permute_batch
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("pod",))
     x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
     spec = P("pod", None)
     xs = jax.device_put(x, NamedSharding(mesh, spec))

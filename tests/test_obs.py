@@ -176,6 +176,17 @@ def test_jax_profile_noop_paths():
         pass
 
 
+def test_jax_profile_writes_trace_and_raises(tmp_path):
+    with jax_profile(str(tmp_path / "a")):
+        jnp.ones(4).block_until_ready()
+    assert list((tmp_path / "a").rglob("*.xplane.pb"))
+    with jax_profile(str(tmp_path / "b")):
+        # a second session cannot start while one runs: the failure surfaces
+        with pytest.raises(RuntimeError):
+            with jax_profile(str(tmp_path / "c")):
+                pass
+
+
 # ------------------------------------------------------------------ stream
 def test_stream_round_trip(tmp_path):
     rec = Recorder(clock=VirtualClock(lambda: 2.0))

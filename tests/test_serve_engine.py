@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models.config import ArchConfig, MLAConfig, MoEConfig, SSMConfig
 from repro.models import transformer as T
 from repro.serve import EngineConfig, Phase, Request, ServeEngine
@@ -70,7 +71,7 @@ def _mesh():
     8-device serve lane."""
     n = len(jax.devices())
     model = 2 if n % 2 == 0 and n > 1 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 # ---------------------------------------------------------------- scheduler
@@ -211,7 +212,7 @@ def test_serve_arg_specs():
 
     from repro.dist.sharding import serve_arg_specs
 
-    mesh = jax.sharding.AbstractMesh(((("data", 4), ("model", 2))))
+    mesh = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
     args = {"token": jax.ShapeDtypeStruct((8, 1), jnp.int32),
             "positions": jax.ShapeDtypeStruct((8,), jnp.int32),
             "odd": jax.ShapeDtypeStruct((3,), jnp.int32)}

@@ -11,10 +11,7 @@ from repro.models import transformer as T
 
 
 def _mesh(shape, names):
-    try:
-        return jax.sharding.AbstractMesh(shape, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
+    return jax.sharding.AbstractMesh(shape, names)
 
 
 MESH = _mesh((16, 16), ("data", "model"))

@@ -7,15 +7,13 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import batch_specs, named, param_specs, spec_for_leaf
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.config import ArchConfig
 
 
 def _mesh(shape, names):
-    try:
-        return jax.sharding.AbstractMesh(shape, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
+    return jax.sharding.AbstractMesh(shape, names)
 
 
 MESH = _mesh((16, 16), ("data", "model"))
@@ -59,7 +57,7 @@ def test_named_device_put_round_trip_host_mesh():
     rule set is exercised end to end)."""
     cfg = ArchConfig(name="tiny", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=129)  # odd vocab on purpose
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     specs = param_specs(params, mesh)
     placed = jax.device_put(params, named(specs, mesh))
