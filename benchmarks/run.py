@@ -27,7 +27,6 @@ MODULES = [
     "round_engine_bench",
     "serve_engine_bench",
     "sim_scenarios_bench",
-    "obs_overhead_bench",
     "pod_gossip_roofline",
 ]
 
@@ -77,7 +76,7 @@ def diff_bench(snap: str | None) -> None:
     """Perf trajectory table: tools/obs_diff.py (--warn-only) of each
     refreshed BENCH_*.json against its pre-sweep snapshot. Report-only —
     a regression past threshold prints loudly but never fails the sweep;
-    gating lives in the modules' own budgets (e.g. obs_overhead_bench)."""
+    gating lives in the modules' own budgets."""
     import glob
     import shutil
     import subprocess

@@ -42,6 +42,7 @@ and data gathers are cheap host-side numpy.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import warnings
@@ -70,6 +71,7 @@ from repro.core.walk import StragglerModel, WalkPlan, sample_walks
 from repro.data.synthetic import FederatedDataset
 from repro.kernels.quantize import payload_quantize_dequantize
 from repro.models.fnn import SmallModel
+from repro.obs import scopes
 from repro.optim.sgd import decreasing_lr
 
 __all__ = ["DFedRWConfig", "DFedRWState", "DFedRW", "RoundMetrics"]
@@ -114,6 +116,11 @@ class RoundMetrics:
     comm_bits_round: float
     comm_bits_busiest_round: float
     gamma_hat: float
+
+
+def _no_span(name: str) -> contextlib.nullcontext:
+    """Stands in for ``Recorder.span`` when no recorder is attached."""
+    return contextlib.nullcontext()
 
 
 def _stack_params(params: Any, n: int) -> Any:
@@ -290,10 +297,6 @@ class DFedRW:
             m, k = walk_devices.shape
 
             n_dev = device_flat.shape[0]
-            chain_flat = device_flat[walk_devices[:, 0]]       # (M, d_pad)
-            bidx_t = jnp.swapaxes(batch_idx, 0, 1)             # (K, M, B) ints
-            xb_all = x[bidx_t]                                 # (K, M, B, ...)
-            yb_all = y[bidx_t]
 
             def scan_body(carry, inputs):
                 chain_flat, qkey = carry
@@ -309,47 +312,57 @@ class DFedRW:
                 # the receiver reconstructs w^k + deq(Q(diff)) in the same
                 # fused kernel pass.
                 if quant_on:
-                    qkey, sub = jax.random.split(qkey)
-                    stepped = payload_quantize_dequantize(
-                        stepped - chain_flat,
-                        spec,
-                        per_message=False,
-                        bits=bits,
-                        s=cfg.quant.s,
-                        key=sub,
-                        base=chain_flat,
-                    )
+                    with jax.named_scope(scopes.WALK_HOP_QDQ):
+                        qkey, sub = jax.random.split(qkey)
+                        stepped = payload_quantize_dequantize(
+                            stepped - chain_flat,
+                            spec,
+                            per_message=False,
+                            bits=bits,
+                            s=cfg.quant.s,
+                            key=sub,
+                            base=chain_flat,
+                        )
                 return (stepped, qkey), (stepped, jnp.sum(grads * grads, axis=1))
 
-            steps = jnp.arange(k, dtype=jnp.int32)
-            # Full unroll: K is small (a handful of walk steps) and the
-            # rolled-loop form costs 5-8x per step on CPU — XLA can neither
-            # fuse across the while-loop boundary nor keep the Pallas call's
-            # buffers in place.
-            (chain_flat, qkey), (traj, grad_sq_traj) = jax.lax.scan(
-                scan_body,
-                (chain_flat, qkey),
-                (xb_all, yb_all, steps),
-                unroll=True,
-            )
+            # The walk's scope holds the scan, so the body's ops and the
+            # scan's own slicing and output stacking fall in it; the hop's
+            # qdq scope nests inside. Full unroll: K is small (a handful of
+            # walk steps) and the rolled-loop form costs 5-8x per step on
+            # CPU — XLA can neither fuse across the while-loop boundary nor
+            # keep the Pallas call's buffers in place.
+            with jax.named_scope(scopes.WALK_SGD):
+                chain_flat = device_flat[walk_devices[:, 0]]   # (M, d_pad)
+                bidx_t = jnp.swapaxes(batch_idx, 0, 1)         # (K, M, B) ints
+                xb_all = x[bidx_t]                             # (K, M, B, ...)
+                yb_all = y[bidx_t]
+                steps = jnp.arange(k, dtype=jnp.int32)
+                (chain_flat, qkey), (traj, grad_sq_traj) = jax.lax.scan(
+                    scan_body,
+                    (chain_flat, qkey),
+                    (xb_all, yb_all, steps),
+                    unroll=True,
+                )
 
             # w^{t,last} scatter, ONCE per round over the whole trajectory:
             # nothing reads the device matrix during the walk, so the
             # sequential per-step scatters collapse into one winner election
             # (priorities replay the (step, chain) write order) plus one
             # unique-row scatter.
-            traj2 = traj.reshape(k * m, d_pad)
-            devs_flat = walk_devices.T.reshape(-1)             # step-major
-            mask_flat = walk_mask.T.reshape(-1)
-            _, wins = elect_writers(devs_flat, mask_flat, n_dev)
-            # losers target distinct OOB rows: dropped, and index uniqueness
-            # holds honestly for the scatter fast path
-            loser_oob = n_dev + jnp.arange(k * m, dtype=devs_flat.dtype)
-            dev_last = device_flat.at[jnp.where(wins, devs_flat, loser_oob)].set(
-                traj2, mode="drop", unique_indices=True
-            )
+            with jax.named_scope(scopes.SCATTER):
+                traj2 = traj.reshape(k * m, d_pad)
+                devs_flat = walk_devices.T.reshape(-1)         # step-major
+                mask_flat = walk_mask.T.reshape(-1)
+                _, wins = elect_writers(devs_flat, mask_flat, n_dev)
+                # losers target distinct OOB rows: dropped, and index
+                # uniqueness holds honestly for the scatter fast path
+                loser_oob = n_dev + jnp.arange(k * m, dtype=devs_flat.dtype)
+                dev_last = device_flat.at[jnp.where(wins, devs_flat, loser_oob)].set(
+                    traj2, mode="drop", unique_indices=True
+                )
 
-            gamma_hat = gamma_hat_from_traj(grad_sq_traj, walk_mask)
+            with jax.named_scope(scopes.LOSS):
+                gamma_hat = gamma_hat_from_traj(grad_sq_traj, walk_mask)
 
             # Decentralized aggregation (Eq. 11 / Eq. 14); padded aggregator
             # slots carry device ids >= n and zero weights -> dropped.
@@ -360,36 +373,41 @@ class DFedRW:
                 # signal, and the payload is the trajectory itself). The
                 # aggregator weight matrix lands each message on every
                 # aggregator listing the sender.
-                qkey, sub = jax.random.split(qkey)
-                base_rows = device_flat[devs_flat]             # (K*M, d_pad)
-                diffs = jnp.where(wins[:, None], traj2 - base_rows, 0.0)
-                deq = payload_quantize_dequantize(
-                    diffs,
-                    spec,
-                    per_message=True,
-                    bits=bits,
-                    s=cfg.quant.s,
-                    key=sub,
-                )
-                hits = agg_rows[:, :, None] == devs_flat[None, None, :]
-                w3 = (jnp.sum(agg_weights[:, :, None] * hits, axis=1)
-                      * wins[None, :].astype(jnp.float32))     # (A, K*M)
-                upd = w3 @ deq                                 # (A, d_pad)
-                base = device_flat[agg_devices]
-                new_device_flat = dev_last.at[agg_devices].set(
-                    base + upd, mode="drop", unique_indices=True
-                )
+                with jax.named_scope(scopes.AGGREGATE_QDQ):
+                    qkey, sub = jax.random.split(qkey)
+                    base_rows = device_flat[devs_flat]         # (K*M, d_pad)
+                    diffs = jnp.where(wins[:, None], traj2 - base_rows, 0.0)
+                    deq = payload_quantize_dequantize(
+                        diffs,
+                        spec,
+                        per_message=True,
+                        bits=bits,
+                        s=cfg.quant.s,
+                        key=sub,
+                    )
+                with jax.named_scope(scopes.AGGREGATE_MIX):
+                    hits = agg_rows[:, :, None] == devs_flat[None, None, :]
+                    w3 = (jnp.sum(agg_weights[:, :, None] * hits, axis=1)
+                          * wins[None, :].astype(jnp.float32))  # (A, K*M)
+                    upd = w3 @ deq                             # (A, d_pad)
+                    base = device_flat[agg_devices]
+                    new_device_flat = dev_last.at[agg_devices].set(
+                        base + upd, mode="drop", unique_indices=True
+                    )
             else:
-                gathered = dev_last[agg_rows]                  # (A, n_agg, d_pad)
-                avg = jnp.sum(agg_weights[..., None] * gathered, axis=1)
-                new_device_flat = dev_last.at[agg_devices].set(
-                    avg, mode="drop", unique_indices=True
-                )
+                with jax.named_scope(scopes.AGGREGATE_MIX):
+                    gathered = dev_last[agg_rows]              # (A, n_agg, d_pad)
+                    avg = jnp.sum(agg_weights[..., None] * gathered, axis=1)
+                    new_device_flat = dev_last.at[agg_devices].set(
+                        avg, mode="drop", unique_indices=True
+                    )
 
             # Mean train loss over the round's final chain models, on their
             # last batch (cheap monitoring signal).
-            losses = jax.vmap(loss_flat)(chain_flat, (xb_all[-1], yb_all[-1]))
-            return new_device_flat, jnp.mean(losses), gamma_hat
+            with jax.named_scope(scopes.LOSS):
+                losses = jax.vmap(loss_flat)(chain_flat, (xb_all[-1], yb_all[-1]))
+                loss = jnp.mean(losses)
+            return new_device_flat, loss, gamma_hat
 
         return round_fn
 
@@ -724,11 +742,8 @@ class DFedRW:
 
     # ------------------------------------------------------------------- run
     def run_round(self, state: DFedRWState, key: jax.Array) -> tuple[DFedRWState, RoundMetrics]:
-        if self.obs is None:
+        with (_no_span if self.obs is None else self.obs.span)(scopes.ENGINE_PLAN):
             plan, bidx, agg = self._plan_round(state)
-        else:
-            with self.obs.span("engine/plan"):
-                plan, bidx, agg = self._plan_round(state)
         return self.execute_round(state, plan, bidx, agg, key)
 
     def round_inputs(self, state: DFedRWState, plan: WalkPlan,
@@ -760,48 +775,52 @@ class DFedRW:
         follow it."""
         cfg = self.cfg
         obs = self.obs
-        t_obs = obs.clock.now() if obs is not None else 0.0
+        span = _no_span if obs is None else obs.span
         bits_eff = cfg.quant.bits if bits is None else int(bits)
         round_fn = self.round_program(bits_eff)
         agg_devices = agg[0]
-        new_params, loss, gamma_hat = round_fn(
-            *self.round_inputs(state, plan, bidx, agg, key))
-        self._programs_run.add(bits_eff)
-        retraces = self.retrace_count
-        if retraces > self._retraces_warned:
-            # Re-armed: every NEW retrace warns again (a monotone counter, not
-            # a fire-once latch — a second unstable shape is still reported).
-            warnings.warn(
-                f"DFedRW round function retraced ({retraces} retrace(s) so "
-                f"far); a plan shape is not stable across rounds (this "
-                f"forfeits compiled-executable reuse)",
-                stacklevel=2,
-            )
-            self._retraces_warned = retraces
-        acct = plan if account_plan is None else account_plan
-        tot, busiest = self._comm_cost_bits(acct, agg, self.flat_spec.d, bits=bits_eff)
-        updated = (state.updated.copy() if state.updated is not None
-                   else np.zeros(self.topo.n, dtype=bool))
-        updated[np.unique(plan.devices[plan.mask])] = True
-        updated[agg_devices[agg_devices < self.topo.n]] = True
-        new_state = DFedRWState(
-            device_params=new_params,
-            round=state.round + 1,
-            global_step=state.global_step + cfg.k_walk,
-            chain_starts=plan.last_device if cfg.chain_mode else None,
-            comm_bits_total=state.comm_bits_total + tot,
-            comm_bits_busiest=state.comm_bits_busiest + busiest,
-            updated=updated,
-        )
-        metrics = RoundMetrics(
-            round=new_state.round,
-            train_loss=float(loss),
-            comm_bits_round=tot,
-            comm_bits_busiest_round=busiest,
-            gamma_hat=float(gamma_hat),
-        )
+        with span(scopes.ENGINE_EXECUTE):
+            with span(scopes.ENGINE_DISPATCH):
+                new_params, loss, gamma_hat = round_fn(
+                    *self.round_inputs(state, plan, bidx, agg, key))
+            with span(scopes.ENGINE_ACCOUNT):
+                self._programs_run.add(bits_eff)
+                retraces = self.retrace_count
+                if retraces > self._retraces_warned:
+                    # Re-armed: every NEW retrace warns again (a monotone
+                    # counter, not a fire-once latch — a second unstable
+                    # shape is still reported).
+                    warnings.warn(
+                        f"DFedRW round function retraced ({retraces} retrace(s) so "
+                        f"far); a plan shape is not stable across rounds (this "
+                        f"forfeits compiled-executable reuse)",
+                        stacklevel=2,
+                    )
+                    self._retraces_warned = retraces
+                acct = plan if account_plan is None else account_plan
+                tot, busiest = self._comm_cost_bits(acct, agg, self.flat_spec.d, bits=bits_eff)
+                updated = (state.updated.copy() if state.updated is not None
+                           else np.zeros(self.topo.n, dtype=bool))
+                updated[np.unique(plan.devices[plan.mask])] = True
+                updated[agg_devices[agg_devices < self.topo.n]] = True
+                new_state = DFedRWState(
+                    device_params=new_params,
+                    round=state.round + 1,
+                    global_step=state.global_step + cfg.k_walk,
+                    chain_starts=plan.last_device if cfg.chain_mode else None,
+                    comm_bits_total=state.comm_bits_total + tot,
+                    comm_bits_busiest=state.comm_bits_busiest + busiest,
+                    updated=updated,
+                )
+            with span(scopes.ENGINE_WAIT):
+                metrics = RoundMetrics(
+                    round=new_state.round,
+                    train_loss=float(loss),
+                    comm_bits_round=tot,
+                    comm_bits_busiest_round=busiest,
+                    gamma_hat=float(gamma_hat),
+                )
         if obs is not None:
-            obs.record_span("engine/execute_round", t_obs, obs.clock.now())
             obs.counter("engine/rounds")
             obs.counter("engine/programs", 1, bits=bits_eff)
             obs.counter("engine/comm_bits", tot, bits=bits_eff)

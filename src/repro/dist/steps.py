@@ -31,6 +31,7 @@ from repro.dist.gossip import GossipConfig, gossip_mix
 from repro.dist.sharding import opt_specs, param_specs
 from repro.models import transformer as T
 from repro.models.config import ArchConfig
+from repro.obs import scopes
 from repro.optim.sgd import decreasing_lr, momentum_sgd
 
 __all__ = [
@@ -154,19 +155,26 @@ def make_fed_train_step(cfg: ArchConfig, mesh, gossip: GossipConfig, *,
     gstep, p_specs, fed_abstract = make_gossip_step(cfg, mesh, gossip, dtype=dtype)
     every = max(int(gossip.every), 1)
 
+    def forward(p, b):
+        with jax.named_scope(scopes.FORWARD):
+            return T.loss_fn(cfg, p, b, remat=remat, unroll=unroll)
+
+    def gossip_step(p, key):
+        with jax.named_scope(scopes.GOSSIP):
+            return gstep(p, key)
+
     def _local_step(params, vel, batch, step):
-        losses, grads = jax.vmap(jax.value_and_grad(
-            lambda p, b: T.loss_fn(cfg, p, b, remat=remat, unroll=unroll)
-        ))(params, batch)
-        lr = decreasing_lr(step + 1, r=lr_r)
-        params, vel = momentum_sgd(params, vel, grads, lr, beta)
+        losses, grads = jax.vmap(jax.value_and_grad(forward))(params, batch)
+        with jax.named_scope(scopes.OPTIMIZER):
+            lr = decreasing_lr(step + 1, r=lr_r)
+            params, vel = momentum_sgd(params, vel, grads, lr, beta)
         return params, vel, losses
 
     if scheduled:
         def step_fn(params, vel, batch, step, do_gossip, key):
             params, vel, loss = _local_step(params, vel, batch, step)
             params = jax.lax.cond(
-                do_gossip, lambda p: gstep(p, key), lambda p: p, params)
+                do_gossip, lambda p: gossip_step(p, key), lambda p: p, params)
             return params, vel, loss
 
         return step_fn, p_specs, fed_abstract
@@ -174,11 +182,11 @@ def make_fed_train_step(cfg: ArchConfig, mesh, gossip: GossipConfig, *,
     def step_fn(params, vel, batch, step, key):
         params, vel, loss = _local_step(params, vel, batch, step)
         if every == 1:
-            params = gstep(params, key)
+            params = gossip_step(params, key)
         else:
             params = jax.lax.cond(
                 (step + 1) % every == 0,
-                lambda p: gstep(p, key), lambda p: p, params)
+                lambda p: gossip_step(p, key), lambda p: p, params)
         return params, vel, loss
 
     return step_fn, p_specs, fed_abstract

@@ -21,6 +21,7 @@ from .provenance import PROVENANCE_KEYS, config_hash, provenance
 from .recorder import (HIST_RESERVOIR, PausableWallClock, Recorder,
                        VirtualClock, WallClock, jax_profile, quantile_line)
 from .report import render_prometheus, render_report
+from .scopes import ENGINE_SPANS, FEDSTEP_SCOPES, ROUND_SCOPES
 from .stream import (OBS_COMPAT_VERSIONS, OBS_SCHEMA, OBS_SCHEMA_VERSION,
                      ObsError, ObsFormatError, ObsSchemaError, ObsStream,
                      make_obs_header)
@@ -59,4 +60,7 @@ __all__ = [
     "critical_paths",
     "straggler_table",
     "render_critical",
+    "ROUND_SCOPES",
+    "FEDSTEP_SCOPES",
+    "ENGINE_SPANS",
 ]

@@ -245,13 +245,22 @@ class Recorder:
     # -- spans -----------------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, **labels: Any) -> Iterator[None]:
-        """Time a block on this recorder's clock; nests freely."""
+        """Time a block on this recorder's clock; nests freely. On a wall
+        clock the block is also a ``jax.profiler.TraceAnnotation`` of the
+        same name, so a profiler trace shows it on its own clock beside the
+        device ops; virtual-time spans are not host intervals and open none."""
         self._clock_check()
-        t0 = self.clock.now()
-        try:
-            yield
-        finally:
-            self.record_span(name, t0, self.clock.now(), **labels)
+        if isinstance(self.clock, WallClock):
+            from jax.profiler import TraceAnnotation
+            annotation = TraceAnnotation(_key(name, labels))
+        else:
+            annotation = contextlib.nullcontext()
+        with annotation:
+            t0 = self.clock.now()
+            try:
+                yield
+            finally:
+                self.record_span(name, t0, self.clock.now(), **labels)
 
     def record_span(self, name: str, t0: float, t1: float,
                     **labels: Any) -> None:

@@ -108,7 +108,9 @@ def render_report(stream) -> str:
     extent = _time_extent(stream, spans)
     if spans:
         rows = []
-        for k in sorted(spans, key=lambda k: -spans[k]["total_s"]):
+        # ties (e.g. host spans priced on a virtual clock, all 0 s) sort by
+        # name, so the summary path and the event rebuild agree
+        for k in sorted(spans, key=lambda k: (-spans[k]["total_s"], k)):
             v = spans[k]
             mean_ms = 1e3 * v["total_s"] / max(v["count"], 1)
             pct = 100.0 * v["total_s"] / extent if extent > 0 else 0.0
