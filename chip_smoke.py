@@ -1,11 +1,16 @@
-"""Smoke run of the three device programs on one TPU chip.
+"""Smoke run of the device programs on one TPU chip.
 
-  python chip_smoke.py               # one chip: protocol round, pod step, serving
+  python chip_smoke.py               # one chip: row merge, protocol round, pod step, serving
   python chip_smoke.py --four-chips  # four chips: the 4-pod gossip fed step only
 
 Phases, each a plain function of a config (``tests/test_chip_smoke.py`` runs
 them on the CPU at smoke widths):
 
+* ``run_merge_phase``: the row-merge kernel (``repro.kernels.rowmerge``)
+  against its row-scatter reference, bit for bit, on writes into the upper
+  half of the rows (lower 8-row groups untouched; partial last row blocks
+  at n=13, 20), several column blocks, with and without add rows, and the
+  32-bit path's two merges.
 * ``run_round_phase``: the DFedRW flat engine at the scale of
   ``benchmarks/round_engine_bench.py`` (fnn_mnist 2FNN, n=100, M=8, K=8),
   3 rounds at 32 bits then 3 at 8 bits through one engine. Checks finite
@@ -142,6 +147,65 @@ def run_round_phase(c: RoundSmoke, seed: int) -> dict:
     if jax.default_backend() == "tpu":  # elsewhere the kernel is interpreted
         assert kernel, "qdq kernel missing from the compiled round program"
     return {"losses": losses, "fp32_diff": diff, "kernel": kernel}
+
+
+# ------------------------------------------------------------ row merge
+@dataclasses.dataclass(frozen=True)
+class MergeSmoke:
+    # (n, K, M, A, d): d of 1000 lane tiles puts the n=20 cases in several
+    # column blocks, the last one partial
+    shapes: tuple = ((13, 2, 5, 3, 3 * 128), (20, 2, 10, 4, 1000 * 128),
+                     (56, 2, 10, 10, 8 * 128))
+
+
+def _merge_case(n, k, m, a, d, seed):
+    """A round's writes into the upper half of the rows, the partial last
+    row block among them, the lower groups untouched: a (K, M) walk with
+    ties and inactive writers, its winners' targets (losers at n), and A
+    distinct aggregators, the last one a padded id n."""
+    from repro.core.flatten import elect_writers
+
+    rng = np.random.default_rng(seed)
+    devs = rng.integers(n - n // 2, n, size=k * m)
+    mask = rng.random(k * m) < 0.7
+    _, wins = elect_writers(jnp.asarray(devs), jnp.asarray(mask), n)
+    targets = jnp.where(wins, jnp.asarray(devs), n).reshape(k, m).astype(jnp.int32)
+    agg = np.append(n - n // 2 + rng.choice(n // 2, size=a - 1, replace=False), n).astype(np.int32)
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape, dtype=np.float32))
+
+    return rand(n, d), targets, rand(k, m, d), jnp.asarray(agg), rand(a, d)
+
+
+def run_merge_phase(c: MergeSmoke, seed: int) -> dict:
+    from repro.kernels.rowmerge import merge_rows
+    from repro.kernels.rowmerge.ref import merge_rows_ref
+
+    kernel = jax.jit(merge_rows, donate_argnums=0)
+    ref = jax.jit(merge_rows_ref)
+    out = {}
+    for i, (n, k, m, a, d) in enumerate(c.shapes):
+        mat, targets, traj, agg, upd = _merge_case(n, k, m, a, d, seed + i)
+        # the 32-bit path: the winners' merge, then averages read from it
+        last = ref(mat, targets, traj)
+        avg = jax.jit(lambda x: (x[agg % n] + x[(agg + 1) % n]) * 0.5)(last)
+        cases = {
+            "set": (ref(mat, targets, traj), lambda x: kernel(x, targets, traj)),
+            "set_add": (ref(mat, targets, traj, agg, upd),
+                        lambda x: kernel(x, targets, traj, agg, upd)),
+            "two_merges": (ref(last, agg, avg),
+                           lambda x: kernel(kernel(x, targets, traj), agg, avg)),
+        }
+        for name, (want, run) in cases.items():
+            got = run(jnp.array(mat, copy=True))
+            bad = int(np.sum(np.any(np.asarray(got) != np.asarray(want), axis=1)))
+            changed = int(np.sum(np.any(np.asarray(want) != np.asarray(mat), axis=1)))
+            say(f"merge: n={n} K={k} M={m} A={a} d={d} {name}: rows changed={changed} "
+                f"rows differing from the scatters={bad}")
+            assert bad == 0 and changed > 0, (n, name, bad, changed)
+            out[(n, name)] = changed
+    return out
 
 
 # ------------------------------------------------------------ pod fed step
@@ -366,7 +430,8 @@ def main(argv=None) -> int:
     say(f"device: {platform} {kind} x{len(devs)}; jax {jax.__version__}; "
         f"compile cache {cache}")
     phases = ([("gossip", run_gossip_phase, GossipSmoke())] if args.four_chips
-              else [("round", run_round_phase, RoundSmoke()),
+              else [("merge", run_merge_phase, MergeSmoke()),
+                    ("round", run_round_phase, RoundSmoke()),
                     ("pod", run_pod_phase, PodSmoke()),
                     ("serve", run_serve_phase, ServeSmoke())])
     for name, fn, cfg in phases:

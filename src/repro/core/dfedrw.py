@@ -13,12 +13,16 @@ single 2-D array op on that matrix:
   2. *Chain SGD* (Eq. 10): the M chain models are M rows; each scan step is
      one vmapped gradient on the flat vectors, masked by chain activity,
      with the paper's globally decreasing step size eta^kbar.
-  3. *w^{t,last} scatter*: all active chains scatter their row into the
-     device matrix in one masked scatter; ties (two chains visiting the same
-     device in one step) break by chain order exactly like the sequential
-     reference (`flatten.masked_scatter_last_wins`).
+  3. *w^{t,last} write*: one winner election over all active chains' rows;
+     ties (two chains visiting the same device in one step) break by chain
+     order exactly like the sequential reference (`flatten.elect_writers`).
   4. *Aggregation* (Eq. 11 / Eq. 14): one gather of the (A, n_agg) neighbor
-     rows, one weighted sum, one scatter.
+     rows, one weighted sum.
+
+The changed rows reach the matrix through the row-merge kernel
+(`repro.kernels.rowmerge.merge_rows`), one in-place pass over the matrix's
+8-row tile groups: with the Eq. 14 updates in the same pass at
+bits < 32, in a pass before and one after the Eq. 11 mix at 32.
 
 QDFedRW (Alg. 2) sends stochastically quantized parameter *differences* on
 every cross-device hop (Eq. 13) and in aggregation (Eq. 14). The flat engine
@@ -70,6 +74,7 @@ from repro.core.quantization import (
 from repro.core.walk import StragglerModel, WalkPlan, sample_walks
 from repro.data.synthetic import FederatedDataset
 from repro.kernels.quantize import payload_quantize_dequantize
+from repro.kernels.rowmerge import ROW_GROUP, merge_rows
 from repro.models.fnn import SmallModel
 from repro.obs import scopes
 from repro.optim.sgd import decreasing_lr
@@ -344,22 +349,22 @@ class DFedRW:
                     unroll=True,
                 )
 
-            # w^{t,last} scatter, ONCE per round over the whole trajectory:
+            # w^{t,last} write, ONCE per round over the whole trajectory:
             # nothing reads the device matrix during the walk, so the
-            # sequential per-step scatters collapse into one winner election
-            # (priorities replay the (step, chain) write order) plus one
-            # unique-row scatter.
+            # sequential per-step writes collapse into one winner election
+            # (priorities replay the (step, chain) write order). The rows
+            # themselves are written by the row merge (`merge_rows`), the
+            # round's last writer of the donated matrix: at bits < 32 in one
+            # pass with the aggregators' Eq. 14 rows, at 32 in a pass of its
+            # own before the Eq. 11 mix reads the merged rows.
             with jax.named_scope(scopes.SCATTER):
-                traj2 = traj.reshape(k * m, d_pad)
                 devs_flat = walk_devices.T.reshape(-1)         # step-major
                 mask_flat = walk_mask.T.reshape(-1)
                 _, wins = elect_writers(devs_flat, mask_flat, n_dev)
-                # losers target distinct OOB rows: dropped, and index
-                # uniqueness holds honestly for the scatter fast path
-                loser_oob = n_dev + jnp.arange(k * m, dtype=devs_flat.dtype)
-                dev_last = device_flat.at[jnp.where(wins, devs_flat, loser_oob)].set(
-                    traj2, mode="drop", unique_indices=True
-                )
+                # losers target an out-of-range row: they write nothing
+                targets = jnp.where(wins, devs_flat, n_dev).reshape(k, m)
+                if not quant_on:
+                    dev_last = merge_rows(device_flat, targets, traj)
 
             with jax.named_scope(scopes.LOSS):
                 gamma_hat = gamma_hat_from_traj(grad_sq_traj, walk_mask)
@@ -376,7 +381,8 @@ class DFedRW:
                 with jax.named_scope(scopes.AGGREGATE_QDQ):
                     qkey, sub = jax.random.split(qkey)
                     base_rows = device_flat[devs_flat]         # (K*M, d_pad)
-                    diffs = jnp.where(wins[:, None], traj2 - base_rows, 0.0)
+                    diffs = jnp.where(wins[:, None],
+                                      traj.reshape(k * m, d_pad) - base_rows, 0.0)
                     deq = payload_quantize_dequantize(
                         diffs,
                         spec,
@@ -390,17 +396,16 @@ class DFedRW:
                     w3 = (jnp.sum(agg_weights[:, :, None] * hits, axis=1)
                           * wins[None, :].astype(jnp.float32))  # (A, K*M)
                     upd = w3 @ deq                             # (A, d_pad)
-                    base = device_flat[agg_devices]
-                    new_device_flat = dev_last.at[agg_devices].set(
-                        base + upd, mode="drop", unique_indices=True
-                    )
+                # an aggregator's row is its pre-round row plus its update,
+                # also where it won the walk's write
+                with jax.named_scope(scopes.SCATTER):
+                    new_device_flat = merge_rows(device_flat, targets, traj,
+                                                 agg_devices, upd)
             else:
                 with jax.named_scope(scopes.AGGREGATE_MIX):
                     gathered = dev_last[agg_rows]              # (A, n_agg, d_pad)
                     avg = jnp.sum(agg_weights[..., None] * gathered, axis=1)
-                    new_device_flat = dev_last.at[agg_devices].set(
-                        avg, mode="drop", unique_indices=True
-                    )
+                    new_device_flat = merge_rows(dev_last, agg_devices, avg)
 
             # Mean train loss over the round's final chain models, on their
             # last batch (cheap monitoring signal).
@@ -801,8 +806,10 @@ class DFedRW:
                 tot, busiest = self._comm_cost_bits(acct, agg, self.flat_spec.d, bits=bits_eff)
                 updated = (state.updated.copy() if state.updated is not None
                            else np.zeros(self.topo.n, dtype=bool))
-                updated[np.unique(plan.devices[plan.mask])] = True
-                updated[agg_devices[agg_devices < self.topo.n]] = True
+                walked = np.unique(plan.devices[plan.mask])
+                aggregated = agg_devices[agg_devices < self.topo.n]
+                updated[walked] = True
+                updated[aggregated] = True
                 new_state = DFedRWState(
                     device_params=new_params,
                     round=state.round + 1,
@@ -826,6 +833,13 @@ class DFedRW:
             obs.counter("engine/comm_bits", tot, bits=bits_eff)
             obs.counter("engine/comm_bits_busiest", busiest)
             obs.counter("engine/steps_executed", int(plan.mask.sum()))
+            # the row merge's passes: one at bits < 32, the walk's rows then
+            # the aggregators' at 32
+            passes = ((np.union1d(walked, aggregated),) if bits_eff < 32
+                      else (walked, aggregated))
+            obs.counter("engine/merge_rows", sum(p.size for p in passes))
+            obs.counter("engine/merge_groups",
+                        sum(np.unique(p // ROW_GROUP).size for p in passes))
             if retraces > self._retraces_obs:
                 obs.counter("engine/retraces", retraces - self._retraces_obs)
                 self._retraces_obs = retraces

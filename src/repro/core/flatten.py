@@ -18,11 +18,12 @@ Padding entries start at zero and stay exactly zero through the whole
 protocol: gradients w.r.t. them vanish (``unflatten`` never reads them),
 quantized diffs at zero are zero, and aggregation is linear.
 
-`masked_scatter_last_wins` is the vectorized replacement for the seed
-engine's per-chain ``lax.fori_loop``/``lax.cond`` scatter: it reproduces the
+`elect_writers` is the vectorized replacement for the seed engine's
+per-chain ``lax.fori_loop``/``lax.cond`` scatter: it reproduces the
 sequential tie-breaking semantics (the highest-index *active* chain visiting
 a device in a step owns its `w^{t,last}` slot) with one scatter-max over
-chain priorities plus one row scatter.
+chain priorities; the winners' rows are then written in one pass
+(`repro.kernels.rowmerge.merge_rows`).
 """
 from __future__ import annotations
 
@@ -40,7 +41,6 @@ __all__ = [
     "flatten_tree",
     "unflatten_tree",
     "elect_writers",
-    "masked_scatter_last_wins",
 ]
 
 LANES = 128
@@ -141,25 +141,3 @@ def elect_writers(
     )
     wins = (winner[idx] == jnp.arange(m, dtype=jnp.int32)) & mask
     return winner, wins
-
-
-def masked_scatter_last_wins(
-    buf: jax.Array, idx: jax.Array, mask: jax.Array, values: jax.Array
-) -> jax.Array:
-    """Vectorized equivalent of the sequential masked row scatter
-
-        for c in range(M):
-            if mask[c]:
-                buf = buf.at[idx[c]].set(values[c])
-
-    i.e. among active writers that hit the same row, the highest index wins
-    (`elect_writers`); a single row scatter then writes only the winners.
-    Losers/inactive writers are redirected to DISTINCT out-of-bounds rows
-    ``n + c`` and dropped, so every index is genuinely unique and the
-    scatter can honestly carry the ``unique_indices`` fast path.
-    """
-    m = idx.shape[0]
-    n = buf.shape[0]
-    _, wins = elect_writers(idx, mask, n)
-    target = jnp.where(wins, idx, n + jnp.arange(m, dtype=idx.dtype))
-    return buf.at[target].set(values, mode="drop", unique_indices=True)

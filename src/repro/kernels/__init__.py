@@ -4,6 +4,9 @@ mode on CPU; see tests/test_kernels_*.py):
 
 - quantize/:   fused stochastic quantization (paper Eq. 12 wire format) --
                the communication hot-spot of QDFedRW.
+- rowmerge/:   the protocol round's row writes into the (n, d_pad) client
+               matrix, in place, one pass of whole (8, 128) tiles per
+               group of rows that holds a changed one.
 - ssd_scan/:   Mamba2 SSD chunked scan (sequential-grid VMEM state) -- the
                SSM archs' training hot-spot.
 - block_attn/: blockwise flash-style causal attention (never materializes

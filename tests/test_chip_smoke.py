@@ -18,6 +18,12 @@ ROUND = C.RoundSmoke(n=10, m_chains=3, k_walk=3, hidden=(32,),
                      n_samples=1000, rounds=2, tol=1e-6)
 
 
+def test_merge_phase_smoke():
+    out = C.run_merge_phase(C.MergeSmoke(shapes=((13, 2, 5, 3, 3 * 128),
+                                                 (20, 2, 10, 4, 2 * 128))), seed=0)
+    assert len(out) == 6
+
+
 def test_round_phase_smoke():
     out = C.run_round_phase(ROUND, seed=0)
     assert set(out["losses"]) == {32, 8}

@@ -12,13 +12,14 @@ from repro.core import DFedRW, DFedRWConfig, QuantConfig, make_topology
 from repro.core.dfedrw import gamma_hat_from_traj
 from repro.core.flatten import (
     LANES,
+    elect_writers,
     flatten_tree,
     make_flat_spec,
-    masked_scatter_last_wins,
     unflatten_tree,
 )
 from repro.core.heterogeneity import partition_similarity
 from repro.data import FederatedDataset, synthetic_image_classification
+from repro.kernels.rowmerge import merge_rows
 from repro.models import make_fnn
 
 
@@ -166,27 +167,28 @@ def test_flatten_round_trip():
 
 @pytest.mark.parametrize("case", range(60))
 def test_scatter_matches_sequential_tie_breaking(case):
-    """Property test: the one-scatter election reproduces the seed engine's
-    sequential semantics exactly — later writers win, inactive writers never
-    write — across random collision patterns (several chains visiting the
-    same device in one step, all-inactive, heavy duplication)."""
+    """Property test: the winner election plus the row merge of the
+    winners' rows (the kernel, interpreted) reproduce the seed engine's
+    sequential semantics exactly — later writers win, inactive writers
+    never write — across random collision patterns (several chains visiting
+    the same device in one step, all-inactive, heavy duplication)."""
     rng = np.random.default_rng(case)
     n = int(rng.integers(2, 13))
     m = int(rng.integers(1, 17))
-    buf = rng.normal(size=(n, 4)).astype(np.float32)
+    buf = rng.normal(size=(n, LANES)).astype(np.float32)
     # small n forces heavy index collisions in most cases
     idx = rng.integers(0, n, size=m).astype(np.int32)
     mask = rng.random(m) < 0.6
-    vals = rng.normal(size=(m, 4)).astype(np.float32)
+    vals = rng.normal(size=(m, LANES)).astype(np.float32)
 
     expect = buf.copy()
     for c in range(m):
         if mask[c]:
             expect[idx[c]] = vals[c]
 
-    out = masked_scatter_last_wins(
-        jnp.asarray(buf), jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(vals)
-    )
+    _, wins = elect_writers(jnp.asarray(idx), jnp.asarray(mask), n)
+    out = merge_rows(jnp.asarray(buf), jnp.where(wins, jnp.asarray(idx), n),
+                     jnp.asarray(vals))
     np.testing.assert_array_equal(np.asarray(out), expect)
 
 

@@ -334,6 +334,36 @@ def test_engine_obs_series(engine_setup):
     assert spans["engine/execute_round"]["count"] == 2
 
 
+@pytest.mark.parametrize("bits", [8, 32])
+def test_engine_merge_counters(bits):
+    """``engine/merge_rows`` and ``engine/merge_groups`` count the rows the
+    round's row merge writes and the 8-row groups holding them, from the
+    plan: one pass at bits < 32, two at 32 (walk winners, aggregators)."""
+    n = 20
+    x, y = synthetic_image_classification(n_samples=400, seed=0, noise=1.0)
+    data = FederatedDataset.from_partition(
+        x, y, partition_similarity(y, n, 50, np.random.default_rng(0)))
+    eng = DFedRW(make_fnn((16,)), data, make_topology("complete", n),
+                 DFedRWConfig(m_chains=4, k_walk=3, batch_size=8,
+                              quant=QuantConfig(bits=bits)))
+    rec = Recorder()
+    eng.attach_obs(rec)
+    key = jax.random.PRNGKey(2)
+    state = eng.init_state(key)
+    rows = groups = 0
+    for r in range(3):
+        plan, bidx = eng.plan_walks(state)
+        agg = eng.plan_aggregation(plan)
+        walked = {int(dv) for dv, on in zip(plan.devices.flat, plan.mask.flat) if on}
+        mixed = {int(a) for a in agg[0] if a < n}
+        passes = [walked | mixed] if bits < 32 else [walked, mixed]
+        rows += sum(len(p) for p in passes)
+        groups += sum(len({i // 8 for i in p}) for p in passes)
+        state, _ = eng.execute_round(state, plan, bidx, agg, jax.random.PRNGKey(r))
+    assert rec.value("engine/merge_rows") == rows > 0
+    assert rec.value("engine/merge_groups") == groups > 3
+
+
 # ------------------------------------------------- simulator: bit-exactness
 SIM_CASES = [("straggler_tail", "heap", 8), ("million_walks", "fleet", 20)]
 

@@ -3,9 +3,11 @@ described TPU v5e (no chip needed): what interpret mode cannot show, such as
 block shapes and memory spaces the TPU compiler refuses. Compiling is not
 running, so these tests say nothing about results or speed."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
@@ -105,3 +107,104 @@ def test_ring_gossip_on_four_chips_is_collective_permute_only(topo, bits):
     text = _hlo(lambda t, k: gossip_mix(t, specs, mesh, cfg, k), tree, key)
     assert "collective-permute" in text
     assert "all-reduce" not in text
+
+
+def _computations(text):
+    """HLO computation name -> its text."""
+    comps, name, lines = {}, None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name, lines = head.group(1), []
+        elif line.startswith("}") and name is not None:
+            comps[name] = "\n".join(lines)
+            name = None
+        elif name is not None:
+            lines.append(line)
+    return comps
+
+
+def _reached(comps, roots):
+    """The computations ``roots`` run, with those they call, transitively."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo += re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", comps[c])
+    return seen
+
+
+@pytest.mark.parametrize("bits", [8, 32])
+def test_round_program_merges_rows_in_place(one_chip, monkeypatch, bits):
+    """The flat round program at a small shape (n=20, d_pad 203,648, where
+    XLA turns a row scatter into a loop of row updates): no loop writes rows
+    of the donated (n, d_pad) matrix one at a time, no scatter writes it,
+    and the row merge's kernel writes it in place (aliased to the matrix
+    operand): once at bits < 32, twice (winners, then the Eq. 11 rows) at
+    32."""
+    from repro.core import DFedRW, DFedRWConfig, QuantConfig, make_topology
+    from repro.core.heterogeneity import partition_similarity
+    from repro.data import FederatedDataset, synthetic_image_classification
+    from repro.models import make_fnn
+
+    # the kernels take their compiled branch, as on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, m, k, b = 20, 4, 3, 8
+    x, y = synthetic_image_classification(n_samples=200, seed=0, noise=1.0)
+    data = FederatedDataset.from_partition(
+        x, y, partition_similarity(y, n, 50, np.random.default_rng(0)))
+    runner = DFedRW(make_fnn((256,)), data, make_topology("complete", n),
+                    DFedRWConfig(m_chains=m, k_walk=k, batch_size=b,
+                                 quant=QuantConfig(bits=bits)))
+    d = runner.flat_spec.d_pad
+    a, n_agg = 5, 5
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg((n, d), jnp.float32), arg((m, k), jnp.int32), arg((m, k), jnp.bool_),
+            arg((m, k, b), jnp.int32), arg((a, n_agg), jnp.int32),
+            arg((a, n_agg), jnp.float32), arg((a,), jnp.int32), arg((), jnp.int32),
+            arg((2,), jnp.uint32))
+    text = runner.round_program(bits).lower(*args).compile().as_text()
+    comps = _computations(text)
+    bodies = re.findall(r"body=%([\w.\-]+)", text)
+    matrix = f"f32[{n},{d}]"
+    for c in _reached(comps, bodies):
+        for line in comps[c].splitlines():
+            assert not (matrix in line and "dynamic-update-slice(" in line), line
+    assert not [line for line in text.splitlines()
+                if re.search(rf"= {re.escape(matrix)}\S* scatter\(", line)]
+    merges = [line for line in text.splitlines()
+              if re.search(rf"%rowmerge[\w.]* = f32\[{n},{d}\]", line)]
+    assert len(merges) == (1 if bits < 32 else 2), merges
+    for line in merges:
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert "output_to_operand_aliasing={{}: (1, {})}" in line, line
+
+
+@pytest.mark.parametrize("n,k,m,kernel", [(13, 2, 5, True), (100_000, 8, 10_000, True),
+                                          (1_000, 8, 13_000, False)])
+def test_merge_rows_kernel_or_scatter_by_size(one_chip, monkeypatch, n, k, m, kernel):
+    """``merge_rows`` compiles to the in-place kernel while a column block
+    of its set and add rows fits in VMEM (n=13: a partial last row block;
+    the fleet shapes' K*M = 80,000 set rows), and to XLA's row scatter
+    beyond that (104,000)."""
+    from repro.kernels.rowmerge import merge_rows
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d, a = 2 * 128, 3
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(merge_rows, donate_argnums=0).lower(
+        arg((n, d), jnp.float32), arg((k, m), jnp.int32), arg((k, m, d), jnp.float32),
+        arg((a,), jnp.int32), arg((a, d), jnp.float32)).compile().as_text()
+    assert ('custom_call_target="tpu_custom_call"' in text) == kernel
+    if kernel:
+        assert "output_to_operand_aliasing={{}: (1, {})}" in text
+    else:
+        assert re.search(rf"= f32\[{n},{d}\]\S* scatter\(", text)
