@@ -10,9 +10,9 @@ and ultimately to replication (indivisible dims such as odd vocabs).
 
 Conventions (documented in docs/ARCHITECTURE.md):
 
-* Stacked leading dims (the scanned ``n_blocks`` / ``encoder`` /
-  ``cross`` layer stacks, and the federated per-pod stack) are never
-  sharded.
+* Stacked leading dims (the scanned ``n_blocks`` / ``dense`` /
+  ``encoder`` / ``cross`` layer stacks, and the federated per-pod stack)
+  are never sharded.
 * **Column-parallel** (model axis on the *output* dim, data/FSDP on the
   input dim): ``wq wk wv w_dkv w_uk w_uv w_gate w_up head router``.
 * **Row-parallel** (model axis on the *input* dim, data on the output):
@@ -68,7 +68,7 @@ _ROW = frozenset({"wo", "w_down"})
 _SSM_DATA_ONLY = frozenset({"in_proj", "out_proj", "conv_w"})
 
 # Param-tree roots whose leaves carry a leading scanned-layer stack dim.
-_STACKED_ROOTS = frozenset({"blocks", "encoder", "cross"})
+_STACKED_ROOTS = frozenset({"blocks", "dense", "encoder", "cross"})
 
 
 def _sizes(mesh) -> dict:

@@ -67,14 +67,10 @@ def resolve_cfg(arch_id: str, shape_name: str) -> ArchConfig:
 
 
 def optimize_cfg(cfg: ArchConfig, global_batch: int = 0) -> ArchConfig:
-    """Beyond-paper perf variant (EXPERIMENTS.md #Perf): grouped MoE
-    dispatch (kills the O(L^2) dispatch einsum at long prefill) and
-    batch-parallel attention for archs whose head count does not divide the
-    16-way model axis (kills the per-layer resharding collectives)."""
+    """Beyond-paper perf variant (EXPERIMENTS.md #Perf): batch-parallel
+    attention for archs whose head count does not divide the 16-way model
+    axis (kills the per-layer resharding collectives)."""
     kw = {}
-    if cfg.moe is not None:
-        gs = int(os.environ.get("REPRO_OPT_MOE_GS", "1024"))
-        kw["moe"] = dataclasses.replace(cfg.moe, group_size=gs)
     if cfg.has_attention and cfg.n_heads % 16 != 0:
         # Full (data, model) batch-parallel attention wins even when the
         # batch pads unevenly (measured: padding 32->256 costs ~4.3x attn
@@ -87,10 +83,11 @@ def optimize_cfg(cfg: ArchConfig, global_batch: int = 0) -> ArchConfig:
 
 
 def scaled_cfg(cfg: ArchConfig, k: int) -> ArchConfig:
-    """Same architecture with k blocks (and proportional encoder depth):
-    used to measure per-scanned-body cost exactly (see corrected_costs)."""
+    """Same architecture with k blocks after its leading dense layers (and
+    proportional encoder depth): used to measure per-scanned-body cost
+    exactly (see corrected_costs)."""
     pat = len(cfg.block_pattern)
-    kwargs = dict(n_layers=pat * k)
+    kwargs = dict(n_layers=cfg.n_dense_layers + pat * k)
     if cfg.enc_dec:
         enc_per_block = cfg.n_enc_layers // cfg.n_blocks
         kwargs["n_enc_layers"] = max(enc_per_block * k, 1)

@@ -9,21 +9,32 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
-__all__ = ["MoEConfig", "MLAConfig", "SSMConfig", "ArchConfig"]
+__all__ = ["MoEConfig", "MLAConfig", "SSMConfig", "YarnConfig", "ArchConfig"]
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """A dropless routed-expert layer. The router scores all ``n_experts``;
+    the layer holds (and computes) experts ``expert_start`` ..
+    ``expert_start + n_held - 1``, one chip's share of an expert-parallel
+    deployment (``n_held`` 0: all of them)."""
+
     n_experts: int = 8
     top_k: int = 2
     n_shared: int = 0          # shared (always-on) experts, deepseek-style
     d_expert: int = 0          # expert FFN hidden dim (0 => use d_ff)
-    capacity_factor: float = 1.0
     router_aux_weight: float = 0.01
-    group_size: int = 0        # >0: dispatch in token groups of this size.
-                               # The one-hot dispatch einsum costs
-                               # O(L * C) ~ O(L^2 * topk / E) per batch row;
-                               # grouping makes it O(L * group_size * topk / E).
+    expert_start: int = 0      # first expert held here
+    n_held: int = 0            # experts held here (0 => all n_experts)
+    norm_topk: bool = True     # renormalise the top-k gates to sum to 1
+    aux: str = "switch"        # balance loss: "switch" (top-1 fractions over
+                               # the batch) | "seq" (DeepSeek's sequence-wise)
+    expert_dtype: str | None = None  # input dtype of the held experts' grouped
+                               # matmul (f32 accumulation); None: the activations'
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +43,18 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 configures it."""
+
+    factor: float = 40.0
+    original_max_positions: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +82,10 @@ class ArchConfig:
     # Which pattern slots are MoE ("moe") vs dense ("dense"); same length as
     # block_pattern, or a single-element tuple broadcast to all slots.
     ffn_pattern: Sequence[str] = ("dense",)
+    n_dense_layers: int = 0             # leading layers of attention + a
+                                        # SwiGLU of width d_ff, before the
+                                        # scanned blocks (DeepSeek's
+                                        # first_k_dense_replace)
     attn_type: str = "gqa"             # "gqa" | "mla"
     qkv_bias: bool = False
     head_dim: int = 0                   # 0 => d_model // n_heads
@@ -70,6 +97,7 @@ class ArchConfig:
     frontend: str = "none"              # "none" | "vision" | "audio" (stubs)
     frontend_tokens: int = 256          # patches/frames prepended (stub)
     rope_theta: float = 1e6
+    yarn: YarnConfig | None = None      # YaRN-scaled rope frequencies
     norm_eps: float = 1e-5
     sliding_window: int = 0             # 0 = full attention
     tie_embeddings: bool = False
@@ -93,11 +121,16 @@ class ArchConfig:
 
     @property
     def n_blocks(self) -> int:
-        assert self.n_layers % len(self.block_pattern) == 0, (
-            f"{self.name}: n_layers {self.n_layers} not divisible by "
+        n = self.n_layers - self.n_dense_layers
+        assert n % len(self.block_pattern) == 0, (
+            f"{self.name}: {n} scanned layers not divisible by "
             f"pattern {len(self.block_pattern)}"
         )
-        return self.n_layers // len(self.block_pattern)
+        return n // len(self.block_pattern)
+
+    @property
+    def rope_dim(self) -> int:
+        return self.mla.qk_rope_dim if self.attn_type == "mla" else self.head_dim_
 
     def ffn_kind(self, slot: int) -> str:
         if len(self.ffn_pattern) == 1:
@@ -129,20 +162,20 @@ class ArchConfig:
         total = v * d  # embed
         if not self.tie_embeddings:
             total += d * v  # lm head
+        if self.attn_type == "mla":
+            m = self.mla
+            attn = d * self.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+            attn += d * (m.kv_lora_rank + m.qk_rope_dim)
+            attn += m.kv_lora_rank * self.n_heads * (m.qk_nope_dim + m.v_head_dim)
+            attn += self.n_heads * m.v_head_dim * d
+        else:
+            attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
+        total += self.n_dense_layers * (attn + 3 * d * ff + 2 * d)
         kinds = list(self.block_pattern)
         for slot, kind in enumerate(kinds):
             per = 0
             if kind == "attn":
-                if self.attn_type == "mla":
-                    m = self.mla
-                    qd = self.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
-                    per += d * qd
-                    per += d * (m.kv_lora_rank + m.qk_rope_dim)
-                    per += m.kv_lora_rank * self.n_heads * (m.qk_nope_dim + m.v_head_dim)
-                    per += self.n_heads * m.v_head_dim * d
-                else:
-                    per += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                    per += self.n_heads * hd * d
+                per += attn
             else:  # mamba
                 s = self.ssm
                 d_in = s.expand * d
@@ -160,7 +193,7 @@ class ArchConfig:
                 mo = self.moe
                 de = mo.d_expert or ff
                 per += d * mo.n_experts  # router
-                per += (mo.n_experts + mo.n_shared) * 3 * d * de
+                per += (mo.held + mo.n_shared) * 3 * d * de
             elif fk == "dense":
                 per += 3 * d * ff  # swiglu
             per += 2 * d  # norms
@@ -183,5 +216,5 @@ class ArchConfig:
         mo = self.moe
         de = mo.d_expert or self.d_ff
         n_moe_slots = sum(1 for s in range(len(self.block_pattern)) if self.ffn_kind(s) == "moe")
-        inactive = (mo.n_experts - mo.top_k) * 3 * self.d_model * de
+        inactive = max(mo.held - mo.top_k, 0) * 3 * self.d_model * de
         return int(full - inactive * n_moe_slots * self.n_blocks)

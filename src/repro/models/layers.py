@@ -4,10 +4,11 @@ Everything is a pure function over explicit param pytrees (dicts), so layer
 blocks can be stacked and scanned (`jax.lax.scan`) for fast lowering of
 deep models, and sharded by path-based PartitionSpec rules.
 
-Covers: RMSNorm, RoPE, GQA attention (QKV-bias, MQA, sliding-window ring
-cache), MLA (DeepSeek compressed-KV attention), SwiGLU FFN, GShard-style
-top-k MoE with shared experts, and the Mamba2 SSD mixer (chunked train scan
-+ O(1) recurrent decode state).
+Covers: RMSNorm, RoPE (with YaRN scaling), GQA attention (QKV-bias, MQA,
+sliding-window ring cache), MLA (DeepSeek compressed-KV attention), SwiGLU
+FFN, a dropless top-k MoE with shared experts that computes the experts it
+holds by a grouped matmul (Pallas megablox), and the Mamba2 SSD mixer
+(chunked train scan + O(1) recurrent decode state).
 
 Dtype policy: params are stored in `param_dtype` (default bf16), activations
 in bf16, softmax/norm statistics in f32.
@@ -15,17 +16,23 @@ in bf16, softmax/norm statistics in f32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-from repro.models.config import ArchConfig
+from repro.models.config import ArchConfig, YarnConfig
+from repro.obs import scopes
 
 __all__ = [
     "rms_norm",
     "rope_freqs",
+    "yarn_mscale",
+    "yarn_inv_freq",
     "apply_rope",
     "init_attn",
     "attn_train",
@@ -58,11 +65,46 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 
 # --------------------------------------------------------------------- RoPE
-def rope_freqs(positions: jax.Array, dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
+def rope_freqs(positions: jax.Array, dim: int, theta: float,
+               yarn: YarnConfig | None = None) -> tuple[jax.Array, jax.Array]:
     """positions (...,) -> cos/sin (..., dim/2) in f32."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(dim, theta, yarn)[0])
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, 0.1 mscale ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: YarnConfig) -> tuple[np.ndarray, int, int]:
+    """YaRN's (dim/2,) inverse frequencies and its ramp's ends (low, high),
+    as DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``: dimensions that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` times are divided
+    by ``factor``, and a linear ramp blends the ones between."""
+    def corr(rotations):
+        return (dim * math.log(yarn.original_max_positions / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    expo = np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim)
+    extra = (1.0 / np.float32(theta) ** expo).astype(np.float32)
+    inter = (1.0 / (np.float32(yarn.factor) * np.float32(theta) ** expo)).astype(np.float32)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / np.float32(high - low if high > low else 0.001), 0, 1)
+    keep = (1.0 - ramp).astype(np.float32)
+    return (inter * (1 - keep) + extra * keep).astype(np.float32), low, high
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -357,6 +399,8 @@ def _mla_attend(p, q_nope, q_rope, c_kv, k_rope, mask, cfg):
     scores = jnp.einsum("bqhr,bkr->bhqk", q_lat, c_kv)
     scores = scores + jnp.einsum("bqhn,bkn->bhqk", q_rope, k_rope)
     scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
     logits = scores.astype(jnp.float32) * scale + mask
     w = jax.nn.softmax(logits, axis=-1).astype(c_kv.dtype)
     ctx = jnp.einsum("bhqk,bkr->bqhr", w, c_kv)  # (b,lq,h,r)
@@ -365,6 +409,7 @@ def _mla_attend(p, q_nope, q_rope, c_kv, k_rope, mask, cfg):
     return out.reshape(b, lq, h * m.v_head_dim) @ p["wo"]
 
 
+@jax.named_scope(scopes.MLA)
 def mla_train(p: dict, x: jax.Array, cfg: ArchConfig, cos, sin) -> jax.Array:
     b, l, _ = x.shape
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, cos, sin)
@@ -381,13 +426,14 @@ def init_cache_mla(cfg: ArchConfig, batch: int, max_len: int, dtype) -> dict:
     }
 
 
+@jax.named_scope(scopes.MLA)
 def mla_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg: ArchConfig,
                active: jax.Array | None = None) -> tuple[jax.Array, dict]:
     """One-token MLA decode; pos scalar or (B,) per-slot (see attn_decode)."""
     b = x.shape[0]
     size = cache["c_kv"].shape[1]
     pos = _slot_positions(pos, b)
-    cos, sin = rope_freqs(pos[:, None], cfg.mla.qk_rope_dim, cfg.rope_theta)
+    cos, sin = rope_freqs(pos[:, None], cfg.mla.qk_rope_dim, cfg.rope_theta, cfg.yarn)
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, cos, sin)
     slot = jnp.mod(pos, size)
     if active is not None:
@@ -400,6 +446,7 @@ def mla_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg: ArchConf
     return y, {"c_kv": cc, "k_rope": cr}
 
 
+@jax.named_scope(scopes.MLA)
 def mla_prefill(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                 n_valid: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, dict]:
     """Chunked MLA prefill into the compressed-KV ring cache (see
@@ -408,7 +455,7 @@ def mla_prefill(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     size = cache["c_kv"].shape[1]
     assert c <= size, f"prefill chunk {c} exceeds ring buffer {size}"
     tok_pos = pos[:, None] + jnp.arange(c)[None, :]
-    cos, sin = rope_freqs(tok_pos, cfg.mla.qk_rope_dim, cfg.rope_theta)
+    cos, sin = rope_freqs(tok_pos, cfg.mla.qk_rope_dim, cfg.rope_theta, cfg.yarn)
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, cos, sin)
     slot = _prefill_write_slots(tok_pos, n_valid, size)
     rows = jnp.arange(b)[:, None]
@@ -437,73 +484,146 @@ def ffn_apply(p: dict, x: jax.Array) -> jax.Array:
 
 # --------------------------------------------------------------------- MoE
 def init_moe(key: jax.Array, cfg: ArchConfig, dtype) -> dict:
+    """The router over all ``n_experts``, the held experts' stacked SwiGLU
+    weights, and the shared experts as one SwiGLU of ``n_shared`` widths."""
     mo = cfg.moe
     d = cfg.d_model
     de = mo.d_expert or cfg.d_ff
     ks = jax.random.split(key, 5)
     p = {
         "router": _dense(ks[0], (d, mo.n_experts), jnp.float32),  # router in f32
-        "w_gate": _dense(ks[1], (mo.n_experts, d, de), dtype),
-        "w_up": _dense(ks[2], (mo.n_experts, d, de), dtype),
-        "w_down": _dense(ks[3], (mo.n_experts, de, d), dtype),
+        "w_gate": _dense(ks[1], (mo.held, d, de), dtype),
+        "w_up": _dense(ks[2], (mo.held, d, de), dtype),
+        "w_down": _dense(ks[3], (mo.held, de, d), dtype),
     }
     if mo.n_shared > 0:
         p["shared"] = init_ffn(ks[4], d, mo.n_shared * de, dtype)
     return p
 
 
-def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, jax.Array]:
-    """GShard-style top-k dispatch with capacity. x (B, L, d).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(a: jax.Array, idx: jax.Array, back: jax.Array, k: int) -> jax.Array:
+    """``a[idx]``, where every row of ``a`` appears ``k`` times in ``idx``
+    and ``back`` lists, for row j's copies in turn, where they sit: the
+    backward pass is then a gather and a sum over the copies, with no
+    scatter-add."""
+    return a[idx]
 
-    Returns (out, aux_loss). Token groups = batch dim (dispatch per row),
-    keeping the dispatch tensors modest and data-sharded. When
-    cfg.moe.group_size > 0 the sequence is further split into groups of that
-    size before dispatch (see MoEConfig.group_size: the dispatch einsum is
-    quadratic in group length, so grouping trades a little routing balance
-    for an O(L/group) dispatch-FLOP reduction -- the beyond-paper perf fix
-    for long-sequence MoE prefill)."""
-    mo = cfg.moe
-    b0, l0, d0 = x.shape
-    gs = mo.group_size
-    if gs and l0 > gs and l0 % gs == 0:
-        x = x.reshape(b0 * (l0 // gs), gs, d0)
-    b, l, d = x.shape
+
+def _take_rows_fwd(a, idx, back, k):
+    return a[idx], back
+
+
+def _take_rows_bwd(k, back, g):
+    return g[back].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+GMM_TILE = (512, 512, 128)   # (rows, contraction, output) tile of the grouped matmul;
+                             # fastest of those tried at DeepSeek-V2-Lite's widths (PERF.md)
+
+
+def _gmm_args(m: int, dtype, mo) -> dict:
+    """Tiling, input dtype and interpret mode of the grouped matmul: inputs
+    in the MoE config's ``expert_dtype``, else their own dtype; interpreted
+    on the CPU, as the repo's other kernels are, and compiled elsewhere."""
+    return {"tiling": (min(GMM_TILE[0], m),) + GMM_TILE[1:],
+            "dtype": jnp.dtype(mo.expert_dtype or dtype),
+            "interpret": jax.default_backend() == "cpu"}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array, mo) -> jax.Array:
+    """rows (m, k), w (G, k, n), sizes (G + 1,) int32: the first G groups of
+    consecutive rows times their own w[g], in f32. Tiles of the last group
+    are never visited and its rows hold no result: callers mask them. A
+    Pallas kernel (megablox); ``mo`` the MoE config (``_gmm_args``)."""
+    return _grouped_matmul_fwd(rows, w, sizes, mo)[0]
+
+
+def _grouped_matmul_fwd(rows, w, sizes, mo):
+    a = _gmm_args(rows.shape[0], rows.dtype, mo)
+    lhs, rhs = rows.astype(a["dtype"]), w.astype(a["dtype"])
+    out = gmm(lhs, rhs, sizes, jnp.float32, a["tiling"], interpret=a["interpret"])
+    return out, (lhs, rhs, sizes)
+
+
+def _grouped_matmul_bwd(mo, res, g):
+    lhs, rhs, sizes = res
+    a = _gmm_args(lhs.shape[0], lhs.dtype, mo)
+    g = g.astype(lhs.dtype)
+    d_rows = gmm(g, rhs, sizes, jnp.float32, a["tiling"], transpose_rhs=True,
+                 interpret=a["interpret"])
+    d_w = tgmm(lhs.swapaxes(0, 1), g, sizes, jnp.float32, a["tiling"],
+               num_actual_groups=rhs.shape[0], interpret=a["interpret"])
+    return d_rows, d_w, None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _balance_loss(probs: jax.Array, idx: jax.Array, mo) -> jax.Array:
+    """The router's balance loss over all experts. probs (b, l, E), idx
+    (b, l, k). "switch": E * sum_e (share of top-1 choices) * (mean
+    probability) over the batch. "seq" (DeepSeek-V2's sequence-wise loss):
+    per sequence, each expert's count among the L*k choices over its
+    uniform count L*k/E, times its mean probability, summed over experts
+    and averaged over sequences."""
     e = mo.n_experts
-    cap = max(8, int(l * mo.top_k * mo.capacity_factor / e))
-    logits = (x.astype(jnp.float32) @ p["router"])  # (b, l, e)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, mo.top_k)  # (b, l, k)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # Load-balance aux loss (Switch): e * sum_e f_e * p_e.
+    if mo.aux == "seq":
+        b, l, k = idx.shape
+        counts = jnp.sum(jax.nn.one_hot(idx.reshape(b, l * k), e, dtype=jnp.float32), axis=1)
+        ce = counts / (l * k / e)
+        return mo.router_aux_weight * jnp.mean(jnp.sum(ce * jnp.mean(probs, axis=1), axis=-1))
     me = jnp.mean(probs, axis=(0, 1))
-    one_hot_top1 = jax.nn.one_hot(gate_idx[..., 0], e)
-    ce = jnp.mean(one_hot_top1, axis=(0, 1))
-    aux = e * jnp.sum(me * ce) * mo.router_aux_weight
+    ce = jnp.mean(jax.nn.one_hot(idx[..., 0], e), axis=(0, 1))
+    return e * jnp.sum(me * ce) * mo.router_aux_weight
 
-    # Position of each token within its expert's capacity, per batch row.
-    sel = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)        # (b, l, k, e)
-    flat = sel.reshape(b, l * mo.top_k, e)
-    pos_in_e = (jnp.cumsum(flat, axis=1) - flat).reshape(b, l, mo.top_k, e)
-    pos = jnp.sum(pos_in_e * sel, axis=-1)                       # (b, l, k)
-    keep = pos < cap
-    gate_vals = gate_vals * keep
 
-    slot = jnp.where(keep, pos, cap).astype(jnp.int32)          # exact: small ints
-    pos_oh = jax.nn.one_hot(slot, cap, dtype=x.dtype)            # (b,l,k,cap)
-    disp = jnp.einsum("blke,blkc->blec", sel.astype(x.dtype), pos_oh)       # (b,l,e,cap)
-    comb = jnp.einsum("blk,blke,blkc->blec", gate_vals.astype(x.dtype),
-                      sel.astype(x.dtype), pos_oh)
+def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, jax.Array]:
+    """Dropless top-k MoE over the experts held here. x (B, L, d).
 
-    xe = jnp.einsum("bld,blec->becd", x, disp)                   # (b,e,cap,d)
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", xe, p["w_gate"]))
-    h = h * jnp.einsum("becd,edf->becf", xe, p["w_up"])
-    ye = jnp.einsum("becf,efd->becd", h, p["w_down"])            # (b,e,cap,d)
-    out = jnp.einsum("becd,blec->bld", ye, comb)
+    The router scores all ``n_experts`` in f32; each token takes its top-k
+    by softmax probability, with the probabilities as gates (renormalised
+    to sum to 1 where ``norm_topk``). The B*L*k assignments are sorted so
+    that those of the held experts come first, grouped by expert, and one
+    grouped matmul per SwiGLU weight computes the held groups alone; no
+    assignment is dropped. Assignments to experts held elsewhere add
+    nothing here. Returns (out, aux_loss)."""
+    mo = cfg.moe
+    b, l, d = x.shape
+    k, held, t = mo.top_k, mo.held, b * l
+    tile = min(GMM_TILE[0], -(-t * k // 128) * 128)
+    m = -(-t * k // tile) * tile                              # rows, whole tiles
+    with jax.named_scope(scopes.MOE_ROUTE):
+        probs = jax.nn.softmax(x.astype(jnp.float32) @ p["router"], axis=-1)  # (b, l, E)
+        gates, idx = jax.lax.top_k(probs, k)                                 # (b, l, k)
+        if mo.norm_topk:
+            gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
+        aux = _balance_loss(probs, idx, mo)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        local = idx.reshape(t * k) - mo.expert_start
+        mine = (local >= 0) & (local < held)                  # per assignment
+        group = jnp.where(mine, local, held)                  # held groups first
+        order = jnp.argsort(group, stable=True)               # sorted -> assignment
+        back = jnp.argsort(order)                             # assignment -> sorted
+        sizes = jnp.sum(jax.nn.one_hot(group, held + 1, dtype=jnp.int32), axis=0)
+        sizes = sizes.at[held].add(m - t * k)                 # the rest: not computed
+        kept = mine[order][:, None]
+        rows = jnp.where(kept, _take_rows(x.reshape(t, d), order // k, back, k), 0)
+        rows = jnp.pad(rows, ((0, m - t * k), (0, 0)))
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        h = jax.nn.silu(_grouped_matmul(rows, p["w_gate"], sizes, mo))
+        h = h * _grouped_matmul(rows, p["w_up"], sizes, mo)
+        ys = _grouped_matmul(h, p["w_down"], sizes, mo)[: t * k]  # (t*k, d), sorted
+    with jax.named_scope(scopes.MOE_COMBINE):
+        y = jnp.where(mine[:, None], _take_rows(ys, back, order, 1), 0).reshape(t, k, d)
+        out = jnp.sum(y * gates.reshape(t, k, 1).astype(y.dtype), axis=1).reshape(b, l, d)
     if "shared" in p:
-        out = out + ffn_apply(p["shared"], x)
-    if (b, l) != (b0, l0):
-        out = out.reshape(b0, l0, d0)
+        with jax.named_scope(scopes.MOE_SHARED):
+            out = out + ffn_apply(p["shared"], x)
     return out, aux
 
 

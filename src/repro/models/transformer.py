@@ -3,6 +3,8 @@
 One implementation covers all ten assigned architectures:
 - layer *blocks* (cfg.block_pattern) are scanned with stacked params, so an
   80-layer model lowers as a single rolled loop (fast multi-arch dry-runs);
+  ``cfg.n_dense_layers`` leading layers (attention + a SwiGLU of width
+  d_ff, DeepSeek's first dense layers) run first, as a scan of their own;
 - each block slot is attn (GQA or MLA) or mamba (SSD), with dense or MoE FFN;
 - enc-dec (seamless) adds a scanned bidirectional encoder + cross-attention;
 - VLM/audio frontends are stubs per the brief: the caller supplies
@@ -56,6 +58,15 @@ def _init_slot(key, cfg: ArchConfig, slot: int, dtype) -> dict:
     return p
 
 
+def _dense_cfg(cfg: ArchConfig) -> ArchConfig:
+    """The leading dense layers as a one-slot pattern: attention, SwiGLU."""
+    return dataclasses.replace(cfg, block_pattern=("attn",), ffn_pattern=("dense",))
+
+
+def _stack(trees: list) -> Any:
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
 def _init_block(key, cfg: ArchConfig, dtype) -> dict:
     keys = jax.random.split(key, len(cfg.block_pattern))
     return {f"slot{i}": _init_slot(keys[i], cfg, i, dtype) for i in range(len(cfg.block_pattern))}
@@ -80,23 +91,23 @@ def init_params(cfg: ArchConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     kb, ke, kh, kenc, kx = jax.random.split(key, 5)
     block_keys = jax.random.split(kb, cfg.n_blocks)
     blocks = [_init_block(block_keys[i], cfg, dtype) for i in range(cfg.n_blocks)]
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks)
     params = {
         "embed": (0.02 * jax.random.normal(ke, (cfg.vocab, cfg.d_model), jnp.float32)).astype(dtype),
         "final_norm": jnp.ones((cfg.d_model,), dtype),
-        "blocks": stacked,
+        "blocks": _stack(blocks),
     }
+    if cfg.n_dense_layers:
+        dense_keys = jax.random.split(jax.random.fold_in(key, 5), cfg.n_dense_layers)
+        params["dense"] = _stack([_init_slot(k, _dense_cfg(cfg), 0, dtype) for k in dense_keys])
     if not cfg.tie_embeddings:
         params["head"] = (
             0.02 * jax.random.normal(kh, (cfg.d_model, cfg.vocab), jnp.float32)
         ).astype(dtype)
     if cfg.enc_dec:
         enc_keys = jax.random.split(kenc, cfg.n_enc_layers)
-        encs = [_init_enc_layer(k, cfg, dtype) for k in enc_keys]
-        params["encoder"] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *encs)
+        params["encoder"] = _stack([_init_enc_layer(k, cfg, dtype) for k in enc_keys])
         x_keys = jax.random.split(kx, cfg.n_blocks)
-        crosses = [_init_cross_layer(k, cfg, dtype) for k in x_keys]
-        params["cross"] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *crosses)
+        params["cross"] = _stack([_init_cross_layer(k, cfg, dtype) for k in x_keys])
         params["enc_norm"] = jnp.ones((cfg.d_model,), dtype)
     return params
 
@@ -179,6 +190,19 @@ def _run_blocks(cfg: ArchConfig, params: dict, x: jax.Array, cos, sin,
     return x, jnp.sum(auxs)
 
 
+def _run_dense(cfg: ArchConfig, params: dict, x: jax.Array, cos, sin, remat: bool = True,
+               unroll: bool = False):
+    """The leading dense layers, scanned over their stack."""
+    dcfg = _dense_cfg(cfg)
+
+    def body(h, lp):
+        return _apply_slot(lp, h, dcfg, 0, cos, sin)[0], None
+
+    body_fn = jax.checkpoint(body) if remat else body
+    x, _ = jax.lax.scan(body_fn, x, params["dense"], unroll=True if unroll else 1)
+    return x
+
+
 def _run_encoder(cfg: ArchConfig, params: dict, embeds: jax.Array, remat: bool = True,
                  unroll: bool = False):
     l = embeds.shape[1]
@@ -213,8 +237,9 @@ def forward_train(cfg: ArchConfig, params: dict, tokens: jax.Array,
         n_front = frontend_embeds.shape[1]
         x = jnp.concatenate([frontend_embeds.astype(dtype), x], axis=1)
     l = x.shape[1]
-    rope_dim = cfg.mla.qk_rope_dim if cfg.attn_type == "mla" else cfg.head_dim_
-    cos, sin = L.rope_freqs(jnp.arange(l), rope_dim, cfg.rope_theta)
+    cos, sin = L.rope_freqs(jnp.arange(l), cfg.rope_dim, cfg.rope_theta, cfg.yarn)
+    if cfg.n_dense_layers:
+        x = _run_dense(cfg, params, x, cos, sin, remat, unroll)
     x, aux = _run_blocks(cfg, params, x, cos, sin, enc_out=enc_out, remat=remat, unroll=unroll)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     if n_front > 0:
@@ -249,12 +274,13 @@ def _init_cache_slot(cfg: ArchConfig, slot: int, batch: int, max_len: int, dtype
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
                enc_len: int = 0) -> dict:
-    """Stacked (n_blocks-leading) cache pytree; enc-dec additionally caches
-    the encoder output for cross-attention."""
-    def stack(make):
+    """Stacked (n_blocks-leading) cache pytree; the leading dense layers'
+    caches stack under "dense"; enc-dec additionally caches the encoder
+    output for cross-attention."""
+    def stack(make, n=cfg.n_blocks):
         one = make()
         return jax.tree_util.tree_map(
-            lambda leaf: jnp.broadcast_to(leaf, (cfg.n_blocks, *leaf.shape)).copy(), one
+            lambda leaf: jnp.broadcast_to(leaf, (n, *leaf.shape)).copy(), one
         )
 
     cache = {
@@ -264,9 +290,89 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
         },
         "pos": jnp.zeros((), jnp.int32),
     }
+    if cfg.n_dense_layers:
+        cache["dense"] = stack(functools.partial(_init_cache_slot, _dense_cfg(cfg), 0, batch,
+                                                 max_len, dtype), cfg.n_dense_layers)
     if cfg.enc_dec:
         cache["enc_out"] = jnp.zeros((batch, enc_len or cfg.frontend_tokens, cfg.d_model), dtype)
     return cache
+
+
+def _ffn_residual(cfg: ArchConfig, slot: int, p: dict, h: jax.Array) -> jax.Array:
+    """The slot's FFN (dense or MoE) and its residual, as the serve paths
+    run it (no balance loss)."""
+    fk = cfg.ffn_kind(slot)
+    if fk == "none":
+        return h
+    hn = L.rms_norm(h, p["norm2"], cfg.norm_eps)
+    if fk == "moe":
+        return h + L.moe_apply(p["ffn"], hn, cfg)[0]
+    return h + L.ffn_apply(p["ffn"], hn)
+
+
+def _decode_slot(cfg: ArchConfig, slot: int, p: dict, h: jax.Array, c: dict, pos,
+                 active) -> tuple[jax.Array, dict]:
+    hn = L.rms_norm(h, p["norm1"], cfg.norm_eps)
+    if cfg.block_pattern[slot] == "attn":
+        if cfg.attn_type == "mla":
+            out, nc = L.mla_decode(p["mixer"], hn, c, pos, cfg, active=active)
+        else:
+            out, nc = L.attn_decode(p["mixer"], hn, c, pos, cfg, active=active)
+    else:
+        out, nc = L.mamba_decode(p["mixer"], hn, c, cfg, active=active)
+    return _ffn_residual(cfg, slot, p, h + out), nc
+
+
+def _prefill_slot(cfg: ArchConfig, slot: int, p: dict, h: jax.Array, c: dict, positions,
+                  n_valid) -> tuple[jax.Array, dict]:
+    hn = L.rms_norm(h, p["norm1"], cfg.norm_eps)
+    if cfg.block_pattern[slot] == "attn":
+        if cfg.attn_type == "mla":
+            out, nc = L.mla_prefill(p["mixer"], hn, c, positions, n_valid, cfg)
+        else:
+            out, nc = L.attn_prefill(p["mixer"], hn, c, positions, n_valid, cfg)
+    else:
+        out, nc = L.mamba_prefill(p["mixer"], hn, c, n_valid, cfg)
+    return _ffn_residual(cfg, slot, p, h + out), nc
+
+
+def _serve_layers(cfg: ArchConfig, params: dict, cache: dict, x: jax.Array, slot_fn,
+                  unroll: bool):
+    """The leading dense layers, then the scanned blocks, each slot through
+    ``slot_fn(cfg, slot, params, h, cache) -> (h, new cache)``, and the
+    enc-dec cross-attention (NoPE over the cached encoder output); returns
+    the final-normed hidden state and the new cache."""
+    unroll = True if unroll else 1
+    new_cache = dict(cache)
+    if cfg.n_dense_layers:
+        dcfg = _dense_cfg(cfg)
+
+        def dense_body(h, scanned):
+            p, c = scanned
+            return slot_fn(dcfg, 0, p, h, c)
+
+        x, new_cache["dense"] = jax.lax.scan(dense_body, x, (params["dense"], cache["dense"]),
+                                             unroll=unroll)
+
+    def body(h, scanned):
+        if cfg.enc_dec:
+            bp, cp, bc = scanned
+        else:
+            (bp, bc), cp = scanned, None
+        new_bc = {}
+        for i in range(len(cfg.block_pattern)):
+            h, new_bc[f"slot{i}"] = slot_fn(cfg, i, bp[f"slot{i}"], h, bc[f"slot{i}"])
+        if cp is not None:
+            hn = L.rms_norm(h, cp["norm"], cfg.norm_eps)
+            h = h + L.attn_train(cp["mixer"], hn, cfg, None, None, kv_override=cache["enc_out"])
+        return h, new_bc
+
+    if cfg.enc_dec:
+        xs = (params["blocks"], params["cross"], cache["slots"])
+    else:
+        xs = (params["blocks"], cache["slots"])
+    x, new_cache["slots"] = jax.lax.scan(body, x, xs, unroll=unroll)
+    return L.rms_norm(x, params["final_norm"], cfg.norm_eps), new_cache
 
 
 def decode_step(cfg: ArchConfig, params: dict, cache: dict, token: jax.Array,
@@ -282,55 +388,11 @@ def decode_step(cfg: ArchConfig, params: dict, cache: dict, token: jax.Array,
     dtype = params["embed"].dtype
     x = params["embed"][token].astype(dtype)
     pos = cache["pos"] if positions is None else positions
-    enc_out = cache.get("enc_out")
-
-    def body(carry, scanned):
-        h = carry
-        if cfg.enc_dec:
-            bp, cp, bc = scanned
-        else:
-            (bp, bc), cp = scanned, None
-        new_bc = {}
-        for i in range(len(cfg.block_pattern)):
-            p = bp[f"slot{i}"]
-            kind = cfg.block_pattern[i]
-            hn = L.rms_norm(h, p["norm1"], cfg.norm_eps)
-            if kind == "attn":
-                if cfg.attn_type == "mla":
-                    out, nc = L.mla_decode(p["mixer"], hn, bc[f"slot{i}"], pos, cfg,
-                                           active=active)
-                else:
-                    out, nc = L.attn_decode(p["mixer"], hn, bc[f"slot{i}"], pos, cfg,
-                                            active=active)
-            else:
-                out, nc = L.mamba_decode(p["mixer"], hn, bc[f"slot{i}"], cfg,
-                                         active=active)
-            h = h + out
-            new_bc[f"slot{i}"] = nc
-            fk = cfg.ffn_kind(i)
-            if fk != "none":
-                hn = L.rms_norm(h, p["norm2"], cfg.norm_eps)
-                if fk == "moe":
-                    out, _ = L.moe_apply(p["ffn"], hn, cfg)
-                else:
-                    out = L.ffn_apply(p["ffn"], hn)
-                h = h + out
-        if cp is not None:
-            hn = L.rms_norm(h, cp["norm"], cfg.norm_eps)
-            cos, sin = L.rope_freqs(jnp.atleast_1d(pos), cfg.head_dim_, cfg.rope_theta)
-            h = h + L.attn_train(cp["mixer"], hn, cfg, cos, sin, kv_override=enc_out)
-        return h, new_bc
-
-    if cfg.enc_dec:
-        xs = (params["blocks"], params["cross"], cache["slots"])
-    else:
-        xs = (params["blocks"], cache["slots"])
-    x, new_slots = jax.lax.scan(body, x, xs, unroll=True if unroll else 1)
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x, new_cache = _serve_layers(
+        cfg, params, cache, x,
+        lambda c_, i, p, h, c: _decode_slot(c_, i, p, h, c, pos, active), unroll)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = x @ head
-    new_cache = dict(cache)
-    new_cache["slots"] = new_slots
     if positions is None:
         new_cache["pos"] = pos + 1
     return logits, new_cache
@@ -349,63 +411,11 @@ def prefill_chunk(cfg: ArchConfig, params: dict, cache: dict, tokens: jax.Array,
     token-at-a-time prefill loop: one call advances every prefilling slot
     by up to C tokens, sharing the decode-path cache layout and numerics
     (attention sums differ only in fp reduction order; the recurrent
-    mixer is bit-identical)."""
+    mixer is bit-identical; the dropless MoE routes every token alone)."""
     dtype = params["embed"].dtype
     x = params["embed"][tokens].astype(dtype)
-    enc_out = cache.get("enc_out")
-
-    def body(carry, scanned):
-        h = carry
-        if cfg.enc_dec:
-            bp, cp, bc = scanned
-        else:
-            (bp, bc), cp = scanned, None
-        new_bc = {}
-        for i in range(len(cfg.block_pattern)):
-            p = bp[f"slot{i}"]
-            kind = cfg.block_pattern[i]
-            hn = L.rms_norm(h, p["norm1"], cfg.norm_eps)
-            if kind == "attn":
-                if cfg.attn_type == "mla":
-                    out, nc = L.mla_prefill(p["mixer"], hn, bc[f"slot{i}"],
-                                            positions, n_valid, cfg)
-                else:
-                    out, nc = L.attn_prefill(p["mixer"], hn, bc[f"slot{i}"],
-                                             positions, n_valid, cfg)
-            else:
-                out, nc = L.mamba_prefill(p["mixer"], hn, bc[f"slot{i}"], n_valid, cfg)
-            h = h + out
-            new_bc[f"slot{i}"] = nc
-            fk = cfg.ffn_kind(i)
-            if fk != "none":
-                hn = L.rms_norm(h, p["norm2"], cfg.norm_eps)
-                if fk == "moe":
-                    # Dispatch per token (groups of length 1), matching the
-                    # decode path's capacity semantics exactly: a chunk-wide
-                    # group would use capacity ~ chunk*top_k/E and can drop
-                    # tokens that token-at-a-time decode never drops,
-                    # breaking the bit-identical-to-sequential contract.
-                    bb, cc_, dd = hn.shape
-                    out, _ = L.moe_apply(p["ffn"], hn.reshape(bb * cc_, 1, dd), cfg)
-                    out = out.reshape(bb, cc_, dd)
-                else:
-                    out = L.ffn_apply(p["ffn"], hn)
-                h = h + out
-        if cp is not None:
-            # Cross-attention is NoPE over the encoder output (the
-            # kv_override path never applies rope), so no per-row freqs.
-            hn = L.rms_norm(h, cp["norm"], cfg.norm_eps)
-            h = h + L.attn_train(cp["mixer"], hn, cfg, None, None, kv_override=enc_out)
-        return h, new_bc
-
-    if cfg.enc_dec:
-        xs = (params["blocks"], params["cross"], cache["slots"])
-    else:
-        xs = (params["blocks"], cache["slots"])
-    x, new_slots = jax.lax.scan(body, x, xs, unroll=True if unroll else 1)
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x, new_cache = _serve_layers(
+        cfg, params, cache, x,
+        lambda c_, i, p, h, c: _prefill_slot(c_, i, p, h, c, positions, n_valid), unroll)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
-    logits = x @ head
-    new_cache = dict(cache)
-    new_cache["slots"] = new_slots
-    return logits, new_cache
+    return x @ head, new_cache

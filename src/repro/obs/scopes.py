@@ -29,7 +29,15 @@ ROUND_SCOPES = (WALK_SGD, WALK_HOP_QDQ, SCATTER, AGGREGATE_QDQ, AGGREGATE_MIX, L
 FORWARD = "forward"                # the loss; its backward is transpose(jvp(forward))
 OPTIMIZER = "optimizer"            # learning rate and momentum SGD
 GOSSIP = "gossip"                  # the gossip mix over the pod axis
-FEDSTEP_SCOPES = (FORWARD, OPTIMIZER, GOSSIP)
+# inside forward (models/layers.py), where the model has them
+MLA = "mla"                        # latent attention: projections, rope, scores, values
+MOE_ROUTE = "moe/route"            # router logits, softmax, top-k, balance loss
+MOE_DISPATCH = "moe/dispatch"      # sort of the assignments, group sizes, row gather
+MOE_EXPERTS = "moe/experts"        # the held experts' grouped matmuls
+MOE_COMBINE = "moe/combine"        # rows back to their tokens, times the gates
+MOE_SHARED = "moe/shared"          # the shared experts' SwiGLU
+FEDSTEP_SCOPES = (FORWARD, OPTIMIZER, GOSSIP, MLA, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
+                  MOE_COMBINE, MOE_SHARED)
 
 # round engine host spans (core/dfedrw.DFedRW.run_round / execute_round)
 ENGINE_PLAN = "engine/plan"                # walk and aggregation planning

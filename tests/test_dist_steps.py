@@ -106,21 +106,34 @@ def test_opt_specs_state_learns_single_device():
     assert losses[-1] < losses[0] - 0.5, (losses[0], losses[-1])
 
 
-def test_moe_group_size_equivalence():
-    """With generous capacity, grouped dispatch computes the same function."""
+@pytest.mark.parametrize("bias", [0.0, 30.0])
+def test_moe_dropless_equivalence(bias):
+    """The sorted, grouped dispatch computes each token's top-k experts,
+    weighted by its gates, with no assignment dropped: also where a biased
+    router sends every token to the same two experts (a capacity buffer
+    would have dropped most of them)."""
     from repro.models import layers as L
 
     cfg = ArchConfig(name="m", n_layers=1, d_model=32, n_heads=4, n_kv_heads=4,
                      d_ff=64, vocab=64, ffn_pattern=("moe",),
-                     moe=MoEConfig(n_experts=4, top_k=2, capacity_factor=8.0))
-    cfg_g = dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe, group_size=8))
+                     moe=MoEConfig(n_experts=4, top_k=2, n_shared=1))
     key = jax.random.PRNGKey(0)
     p = L.init_moe(key, cfg, jnp.float32)
-    x = jax.random.normal(key, (2, 32, 32), jnp.float32)
-    y0, _ = L.moe_apply(p, x, cfg)
-    y1, _ = L.moe_apply(p, x, cfg_g)
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), atol=1e-5, rtol=1e-5)
+    p["router"] = p["router"].at[:, 1:3].add(bias)
+    x = jax.random.normal(key, (2, 32, 32), jnp.float32) + 1.0    # sum(x) > 0: biased logits
+    with jax.default_matmul_precision("highest"):
+        y, _ = L.moe_apply(p, x, cfg)
+        probs = jax.nn.softmax(x @ p["router"], axis=-1)
+        gates, idx = jax.lax.top_k(probs, 2)
+        gates = gates / gates.sum(-1, keepdims=True)
+        experts = jnp.stack([L.ffn_apply({n: p[n][e] for n in ("w_gate", "w_up", "w_down")}, x)
+                             for e in range(4)], axis=2)          # (b, l, E, d)
+        want = jnp.sum(jnp.take_along_axis(experts, idx[..., None], axis=2)
+                       * gates[..., None], axis=2) + L.ffn_apply(p["shared"], x)
+    if bias:
+        assert bool(jnp.all(jnp.sort(idx, axis=-1) == jnp.array([1, 2])))
+    # float32 on the CPU: only summation order differs
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5, rtol=1e-5)
 
 
 def test_optimize_cfg_rules():
@@ -132,8 +145,8 @@ def test_optimize_cfg_rules():
     assert q25.attn_batch_parallel  # 40 heads % 16 != 0
     q2 = D.optimize_cfg(get_arch("qwen2-72b"))
     assert not q2.attn_batch_parallel  # 64 heads divides
-    gk = D.optimize_cfg(get_arch("grok-1-314b"))
-    assert gk.moe.group_size == 1024
+    gk = get_arch("grok-1-314b")
+    assert D.optimize_cfg(gk) == gk  # 48 heads divides; the MoE is dropless
     mm = D.optimize_cfg(get_arch("mamba2-130m"))
     assert mm == get_arch("mamba2-130m")  # nothing to do
 

@@ -74,7 +74,7 @@ def test_full_config_matches_assignment(arch_id):
     cfg = get_arch(arch_id)
     expect = {
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
-        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
         "mamba2-130m": (24, 768, 12, 12, 0, 50280),
         "qwen2-72b": (80, 8192, 64, 8, 29568, 152064),
         "yi-6b": (32, 4096, 32, 4, 11008, 64000),

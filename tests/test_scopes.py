@@ -103,7 +103,7 @@ def test_fed_step_carries_forward_backward_optimizer_gossip():
     assert line, r.stdout[-2000:] + r.stderr[-2000:]
     for every, names in json.loads(line[0].split(" ", 1)[1]).items():
         comps = {c for n in names for part in n.split(";") for c in part.split("/")}
-        forward, optimizer, gossip = FEDSTEP_SCOPES
+        forward, optimizer, gossip = FEDSTEP_SCOPES[:3]
         assert any(f"jvp({forward})" in c and "transpose" not in c for c in comps), every
         assert any(f"transpose(jvp({forward}))" in c for c in comps), every
         assert _has_scope(set(names), optimizer), every
@@ -112,6 +112,28 @@ def test_fed_step_carries_forward_backward_optimizer_gossip():
         assert ppermutes and all(_has_scope({n}, gossip) for n in ppermutes), every
         if every == "2":
             assert any("/cond/" in n and _has_scope({n}, gossip) for n in names)
+
+
+def test_moe_fed_step_carries_the_layer_scopes():
+    """The DeepSeek smoke model's pod step: MLA and the expert layer's
+    parts carry their scopes, forward and backward."""
+    from repro.configs import get_smoke
+    from repro.dist.gossip import GossipConfig
+    from repro.dist.steps import make_fed_train_step
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"), jax.devices()[:1])
+    fn, _, abstract = make_fed_train_step(get_smoke("deepseek-v2-lite-16b"), mesh,
+                                          GossipConfig(axis="pod"), remat=True,
+                                          dtype=jnp.float32)
+    tok = jax.ShapeDtypeStruct((1, 2, 16), jnp.int32)
+    with mesh:
+        names = _op_names(jax.jit(fn).lower(abstract, abstract, dict(tokens=tok, labels=tok),
+                                            jnp.int32(0), jax.random.PRNGKey(0))
+                          .compile().as_text())
+    missing = [s for s in FEDSTEP_SCOPES[3:] if not _has_scope(names, s)]
+    assert not missing, missing
+    assert any("transpose(jvp(forward))" in n and "moe/experts" in n for n in names)
 
 
 def _host_events(logdir) -> list:

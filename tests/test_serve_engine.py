@@ -24,7 +24,7 @@ SSM = ArchConfig(name="s", n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
 HYBRID = ArchConfig(name="h", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                     d_ff=128, vocab=64, block_pattern=("mamba", "attn"),
                     ffn_pattern=("dense", "moe"),
-                    moe=MoEConfig(n_experts=4, top_k=2, capacity_factor=4.0),
+                    moe=MoEConfig(n_experts=4, top_k=2),
                     ssm=SSMConfig(state_dim=16, head_dim=16, chunk=8))
 MLA = ArchConfig(name="m", n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
                  d_ff=128, vocab=64, attn_type="mla",
