@@ -13,22 +13,26 @@ pod's tensors one topology hop along the axis (receiver i takes the shard of
 device.
 
 Implementation: `shard_map` + `lax.ppermute` collective permutes, one per
-offset. With ``quant_bits < 32`` the transmitted payloads go through the
-stochastic quantizer (paper Eq. 12) before the permute — the wire round trip
-Q^-1(Q(w)) with a per-(device, offset) key — which is what QDFedRW sends on
-every cross-device edge.
+offset. With ``quant_bits < 32`` the sender quantizes each payload (paper
+Eq. 12) with a per-(leaf, offset, device) key and permutes the wire itself:
+the signed indices as int8 codes and the (s, ||w||) header as one f32[2].
+The receiver dequantizes (idx * s * ||w||) inside its weighted sum, so no
+float payload crosses the link or is written on either side. At 32 bits the
+leaf goes as it is. `wire_bytes` counts what one pod sends per round.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.quantization import QuantConfig, dequantize, quantize
+from repro.core.quantization import (QuantConfig, Quantized, dequantize, quantize,
+                                     validate_wire_bits)
 
 __all__ = [
     "GossipConfig",
@@ -36,6 +40,7 @@ __all__ = [
     "make_expander_weights",
     "mixing_weights",
     "gossip_mix",
+    "wire_bytes",
     "walk_permute_batch",
 ]
 
@@ -96,10 +101,25 @@ def make_expander_weights(n: int, cfg: GossipConfig) -> list[tuple[int, float]]:
     return mixing_weights(n, dataclasses.replace(cfg, topology="expander"))
 
 
-def _wire_round_trip(xs: jax.Array, bits: int, key: jax.Array) -> jax.Array:
-    """Simulate the quantized wire: deq(Q(x)) with the Eq. 12 adaptive grid."""
-    q = quantize(xs, QuantConfig(bits=bits), key)
-    return dequantize(q, dtype=xs.dtype).reshape(xs.shape)
+HEADER_BYTES = 8  # (s, ||w||) as f32[2]: the Eq. 12 side information
+
+
+def wire_bytes(tree: Any, cfg: GossipConfig, n: int) -> int:
+    """Bytes one pod sends per `gossip_mix` round over an ``n``-pod axis.
+
+    ``tree`` is the stacked tree `gossip_mix` takes (arrays or
+    ShapeDtypeStructs, one model per pod), so a pod holds 1/n of each leaf
+    and sends it once per topology offset: as int8 codes plus the f32[2]
+    header when ``quant_bits < 32``, in the leaf's dtype at 32 bits. One
+    header per (leaf, offset) assumes a pod's leaf lives on one device; a
+    pod split over k devices sends k headers per (leaf, offset)."""
+    bits = validate_wire_bits(cfg.quant_bits)
+    per_edge = 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        size = math.prod(leaf.shape) // n
+        per_edge += (size + HEADER_BYTES if bits < 32
+                     else size * np.dtype(leaf.dtype).itemsize)
+    return len(cfg.offsets(n)) * per_edge
 
 
 def gossip_mix(tree: Any, specs: Any, mesh, cfg: GossipConfig,
@@ -108,12 +128,14 @@ def gossip_mix(tree: Any, specs: Any, mesh, cfg: GossipConfig,
 
     ``tree`` is a pytree of arrays sharded over ``mesh`` with PartitionSpecs
     ``specs``; receiver i gets sum_{(o, w)} w * shard_{(i+o) mod n}. With
-    ``cfg.quant_bits < 32`` every transmitted (non-self) payload goes through
-    the stochastic quantizer, seeded per (device, offset, leaf).
+    ``cfg.quant_bits < 32`` every transmitted (non-self) payload is
+    stochastically quantized, seeded per (leaf, offset, device), and sent as
+    int8 codes with its (s, ||w||) header; the receiver dequantizes it.
     """
     n = mesh.shape[cfg.axis]
     pairs = mixing_weights(n, cfg)
-    quantized = cfg.quant_bits < 32
+    bits = validate_wire_bits(cfg.quant_bits)
+    quantized = bits < 32
     if quantized and key is None:
         raise ValueError("gossip_mix with quant_bits < 32 requires a PRNG key")
     if key is None:
@@ -125,15 +147,19 @@ def gossip_mix(tree: Any, specs: Any, mesh, cfg: GossipConfig,
         for li, xs in enumerate(leaves):
             acc = pairs[0][1] * xs
             for oi, (off, w) in enumerate(pairs[1:]):
-                payload = xs
+                # receiver i takes the shard of sender (i + off) mod n.
+                perm = [((i + off) % n, i) for i in range(n)]
                 if quantized:
                     k = key_rep
                     for salt in (li, oi, me):  # collision-free per (leaf, edge, device)
                         k = jax.random.fold_in(k, salt)
-                    payload = _wire_round_trip(xs, cfg.quant_bits, k)
-                # receiver i takes the shard of sender (i + off) mod n.
-                perm = [((i + off) % n, i) for i in range(n)]
-                acc = acc + w * jax.lax.ppermute(payload, cfg.axis, perm)
+                    q = quantize(xs, QuantConfig(bits=bits), k)
+                    codes = jax.lax.ppermute(q.indices.astype(jnp.int8), cfg.axis, perm)
+                    s, norm = jax.lax.ppermute(jnp.stack([q.s, q.norm]), cfg.axis, perm)
+                    got = dequantize(Quantized(codes, s, norm, q.shape), dtype=xs.dtype)
+                else:
+                    got = jax.lax.ppermute(xs, cfg.axis, perm)
+                acc = acc + w * got
             out.append(acc)
         return tuple(out)
 

@@ -171,7 +171,7 @@ def fed_pod_main(args, cfg, key, rng) -> list[float]:
     import jax
     import jax.numpy as jnp
 
-    from repro.dist.gossip import GossipConfig
+    from repro.dist.gossip import GossipConfig, wire_bytes
     from repro.dist.sharding import batch_specs, named
     from repro.dist.steps import make_fed_train_step
     from repro.launch.mesh import make_pod_mesh
@@ -185,7 +185,8 @@ def fed_pod_main(args, cfg, key, rng) -> list[float]:
     params, vel = init_fed_state(cfg, mesh, p_specs, key)
     jitted = jax.jit(step_fn, donate_argnums=(0, 1))
     print(f"fed pod mode: {g} pods x data={dict(mesh.shape)['data']} "
-          f"topology={gossip.topology} every={gossip.every} bits={gossip.quant_bits}")
+          f"topology={gossip.topology} every={gossip.every} bits={gossip.quant_bits} "
+          f"wire_bytes/pod={wire_bytes(params, gossip, g)}")
     b_shard = None  # batch shapes are constant: compute shardings once
     losses = []
     with mesh:

@@ -41,7 +41,7 @@ def test_fed_pod_args_reach_gossip_config():
                     "--pods", "4", "--gossip-every", "2", "--bits", "8",
                     "--topology", "expander", "--steps", "4"])
     assert ("fed pod mode: 4 pods x data=2 topology=expander "
-            "every=2 bits=8") in out
+            "every=2 bits=8 wire_bytes/pod=") in out
     m = re.search(r"done \(inter-pod param spread=([0-9.]+)\)", out)
     assert m, out[-2000:]
     assert out.count("step ") == 4

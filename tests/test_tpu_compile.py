@@ -87,11 +87,24 @@ def test_qdq_rows_kernel_with_drawn_uniforms_compiles(one_chip):
     assert "tpu_custom_call" in _hlo(qdq, w, w, per_row, per_row)
 
 
+_PERMUTE_RE = re.compile(
+    r"= \(?(\w+)\[([\d,]*)\][^ ]* .*?collective-permute(?:-start)?\(")
+_ITEMSIZE = {"s8": 1, "bf16": 2, "f32": 4}
+
+
+def _permutes(text):
+    """(dtype, element count) of every collective-permute's operand."""
+    return [(dt, int(np.prod([int(d) for d in dims.split(",") if d])))
+            for dt, dims in _PERMUTE_RE.findall(text)]
+
+
 @pytest.mark.parametrize("bits", [32, 8])
 def test_ring_gossip_on_four_chips_is_collective_permute_only(topo, bits):
     """DFedRW's claim at pod scale: the gossip mix moves params between
-    chips by collective-permute and never all-reduces."""
-    from repro.dist.gossip import GossipConfig, gossip_mix
+    chips by collective-permute and never all-reduces. At 8 bits the leaf
+    permutes carry the int8 codes and together move `wire_bytes`; at 32
+    bits they carry the f32 leaf."""
+    from repro.dist.gossip import GossipConfig, gossip_mix, wire_bytes
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((4,), ("pod",), topo.devices)
@@ -107,6 +120,11 @@ def test_ring_gossip_on_four_chips_is_collective_permute_only(topo, bits):
     text = _hlo(lambda t, k: gossip_mix(t, specs, mesh, cfg, k), tree, key)
     assert "collective-permute" in text
     assert "all-reduce" not in text
+    permutes = _permutes(text)
+    leaf_sizes = {1024 * 128, 256}
+    leaf_types = {dt for dt, size in permutes if size in leaf_sizes}
+    assert leaf_types == ({"s8"} if bits == 8 else {"f32"}), permutes
+    assert sum(size * _ITEMSIZE[dt] for dt, size in permutes) == wire_bytes(tree, cfg, 4)
 
 
 def _computations(text):
