@@ -29,9 +29,9 @@ every cross-device hop (Eq. 13) and in aggregation (Eq. 14). The flat engine
 runs the quantizer as ONE fused Pallas kernel call per payload
 (`repro.kernels.quantize.payload_quantize_dequantize`): per-leaf segments of
 the flat buffer carry their own adaptive grid (segment-wise norms), so the
-wire format is identical to the per-leaf reference in
-``repro.core.quantization`` — which stays the bit-exact oracle, validated by
-the parity tests in tests/test_flat_engine.py.
+wire format is that of the per-leaf reference in ``repro.core.quantization``
+(parity tests in tests/test_flat_engine.py); the kernel's bit-exact oracle
+is ``repro.kernels.quantize.ref``.
 
 ``DFedRWConfig.engine`` selects the implementation: ``"flat"`` (default,
 vectorized + Pallas) or ``"reference"`` (the seed per-leaf/per-chain
@@ -42,7 +42,10 @@ consumed by it) and guards against shape-induced retraces (aggregation
 plans are padded to fixed shapes).
 
 The per-round inner loop is jitted once per (M, K, batch) shape; walk plans
-and data gathers are cheap host-side numpy.
+and data gathers are cheap host-side numpy. The flat round program is built
+from two halves, the walk (step 2) and the finalize (steps 3-4)
+(`DFedRW.round_parts`), which the metal replay (``repro.sim.metal``) also
+runs, sharding the walk over devices.
 """
 from __future__ import annotations
 
@@ -191,6 +194,7 @@ class DFedRW:
         # fixed plan shapes; _programs_run tracks how many distinct programs
         # have executed so the retrace warning stays meaningful.
         self._round_fns: dict[int, Any] = {}
+        self._round_parts: dict[int, tuple] = {}
         self._programs_run: set[int] = set()
         self.round_program(cfg.quant.bits)
 
@@ -273,7 +277,21 @@ class DFedRW:
         return state.device_params
 
     # ---------------------------------------------------------- flat engine
-    def _build_round_fn_flat(self, bits: int):
+    def round_parts(self, bits: int):
+        """The flat round program's two halves for a wire width, built once
+        and not jitted: ``walk``, the chain-SGD scan with its Eq. 13 hop qdq
+        over the gathered (K, M, B, ...) ``(xb, yb)`` batches, returning the
+        scan's ``(chain_final, key), (traj, grad_sq)``; and ``finalize``, the
+        winner election, `merge_rows` writes, Eq. 11/14 aggregation and
+        loss, returning ``(new_device_flat, loss, gamma_hat)``.
+        ``round_program(bits)`` is the jit of finalize after walk; the metal
+        replay (`repro.sim.metal`) shards the walk and jits the finalize."""
+        bits = validate_wire_bits(int(bits))
+        if bits not in self._round_parts:
+            self._round_parts[bits] = self._build_round_parts(bits)
+        return self._round_parts[bits]
+
+    def _build_round_parts(self, bits: int):
         cfg = self.cfg
         quant_on = bits < 32
         model = self.model
@@ -285,24 +303,7 @@ class DFedRW:
 
         grad_fn = jax.vmap(jax.grad(loss_flat))
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def round_fn(
-            device_flat,              # (n, d_pad) f32 — donated
-            walk_devices,             # (M, K) int32
-            walk_mask,                # (M, K) bool
-            batch_idx,                # (M, K, B) int64 into global data
-            agg_rows,                 # (A, n_agg) int32 neighbor ids per aggregator
-            agg_weights,              # (A, n_agg) f32 (n_l/m, zero-padded)
-            agg_devices,              # (A,) int32 aggregating device ids (n = pad)
-            kbar0,                    # scalar int32: global step before round
-            qkey,                     # PRNG key for quantization
-        ):
-            self._trace_count += 1    # python side effect: fires on (re)trace only
-            x, y = self._x, self._y
-            m, k = walk_devices.shape
-
-            n_dev = device_flat.shape[0]
-
+        def walk(chain_flat, batches, walk_mask, kbar0, qkey):
             def scan_body(carry, inputs):
                 chain_flat, qkey = carry
                 xb, yb, step_k = inputs
@@ -330,24 +331,29 @@ class DFedRW:
                         )
                 return (stepped, qkey), (stepped, jnp.sum(grads * grads, axis=1))
 
-            # The walk's scope holds the scan, so the body's ops and the
-            # scan's own slicing and output stacking fall in it; the hop's
-            # qdq scope nests inside. Full unroll: K is small (a handful of
-            # walk steps) and the rolled-loop form costs 5-8x per step on
-            # CPU — XLA can neither fuse across the while-loop boundary nor
-            # keep the Pallas call's buffers in place.
-            with jax.named_scope(scopes.WALK_SGD):
-                chain_flat = device_flat[walk_devices[:, 0]]   # (M, d_pad)
-                bidx_t = jnp.swapaxes(batch_idx, 0, 1)         # (K, M, B) ints
-                xb_all = x[bidx_t]                             # (K, M, B, ...)
-                yb_all = y[bidx_t]
-                steps = jnp.arange(k, dtype=jnp.int32)
-                (chain_flat, qkey), (traj, grad_sq_traj) = jax.lax.scan(
-                    scan_body,
-                    (chain_flat, qkey),
-                    (xb_all, yb_all, steps),
-                    unroll=True,
-                )
+            # Full unroll: K is small (a handful of walk steps) and the
+            # rolled-loop form costs 5-8x per step on CPU — XLA can neither
+            # fuse across the while-loop boundary nor keep the Pallas call's
+            # buffers in place.
+            steps = jnp.arange(walk_mask.shape[1], dtype=jnp.int32)
+            return jax.lax.scan(
+                scan_body, (chain_flat, qkey), (*batches, steps), unroll=True)
+
+        def finalize(
+            device_flat,              # (n, d_pad) f32 pre-round matrix
+            traj,                     # (K, M, d_pad) f32 walk trajectory
+            grad_sq_traj,             # (K, M) f32
+            walk_devices,             # (M, K) int32
+            walk_mask,                # (M, K) bool
+            agg_rows,                 # (A, n_agg) int32 neighbor ids per aggregator
+            agg_weights,              # (A, n_agg) f32 (n_l/m, zero-padded)
+            agg_devices,              # (A,) int32 aggregating device ids (n = pad)
+            agg_key,                  # PRNG key of the Eq. 14 qdq
+            chain_final,              # (M, d_pad) f32 final chain models
+            last_batch,               # their last (xb, yb)
+        ):
+            k, m = traj.shape[:2]
+            n_dev = device_flat.shape[0]
 
             # w^{t,last} write, ONCE per round over the whole trajectory:
             # nothing reads the device matrix during the walk, so the
@@ -379,7 +385,6 @@ class DFedRW:
                 # aggregator weight matrix lands each message on every
                 # aggregator listing the sender.
                 with jax.named_scope(scopes.AGGREGATE_QDQ):
-                    qkey, sub = jax.random.split(qkey)
                     base_rows = device_flat[devs_flat]         # (K*M, d_pad)
                     diffs = jnp.where(wins[:, None],
                                       traj.reshape(k * m, d_pad) - base_rows, 0.0)
@@ -389,7 +394,7 @@ class DFedRW:
                         per_message=True,
                         bits=bits,
                         s=cfg.quant.s,
-                        key=sub,
+                        key=agg_key,
                     )
                 with jax.named_scope(scopes.AGGREGATE_MIX):
                     hits = agg_rows[:, :, None] == devs_flat[None, None, :]
@@ -410,9 +415,46 @@ class DFedRW:
             # Mean train loss over the round's final chain models, on their
             # last batch (cheap monitoring signal).
             with jax.named_scope(scopes.LOSS):
-                losses = jax.vmap(loss_flat)(chain_flat, (xb_all[-1], yb_all[-1]))
+                losses = jax.vmap(loss_flat)(chain_final, last_batch)
                 loss = jnp.mean(losses)
             return new_device_flat, loss, gamma_hat
+
+        return walk, finalize
+
+    def _build_round_fn_flat(self, bits: int):
+        walk, finalize = self.round_parts(bits)
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def round_fn(
+            device_flat,              # (n, d_pad) f32 — donated
+            walk_devices,             # (M, K) int32
+            walk_mask,                # (M, K) bool
+            batch_idx,                # (M, K, B) int64 into global data
+            agg_rows,                 # (A, n_agg) int32 neighbor ids per aggregator
+            agg_weights,              # (A, n_agg) f32 (n_l/m, zero-padded)
+            agg_devices,              # (A,) int32 aggregating device ids (n = pad)
+            kbar0,                    # scalar int32: global step before round
+            qkey,                     # PRNG key for quantization
+        ):
+            self._trace_count += 1    # python side effect: fires on (re)trace only
+            x, y = self._x, self._y
+            # The walk's scope holds the gathers and the scan, so the body's
+            # ops and the scan's own slicing and output stacking fall in it;
+            # the hop's qdq scope nests inside.
+            with jax.named_scope(scopes.WALK_SGD):
+                chain_flat = device_flat[walk_devices[:, 0]]   # (M, d_pad)
+                bidx_t = jnp.swapaxes(batch_idx, 0, 1)         # (K, M, B) ints
+                xb_all = x[bidx_t]                             # (K, M, B, ...)
+                yb_all = y[bidx_t]
+                (chain_flat, qkey), (traj, grad_sq_traj) = walk(
+                    chain_flat, (xb_all, yb_all), walk_mask, kbar0, qkey)
+            agg_key = qkey
+            if bits < 32:
+                with jax.named_scope(scopes.AGGREGATE_QDQ):
+                    _, agg_key = jax.random.split(qkey)
+            return finalize(device_flat, traj, grad_sq_traj, walk_devices,
+                            walk_mask, agg_rows, agg_weights, agg_devices, agg_key,
+                            chain_flat, (xb_all[-1], yb_all[-1]))
 
         return round_fn
 

@@ -37,11 +37,13 @@ the whole payload's quantize -> dequantize round trip as one fused Pallas
 kernel call with per-row (s, norm) operands.
 
 This module is the pure-jnp reference implementation; the Pallas TPU kernel
-in repro/kernels/quantize/ is bit-compatible (same grid, same rounding
-given the same uniforms) and is validated against `quantize`/`dequantize`
-below (tests/test_kernels_quantize.py). The flat engine's kernel draws its
-stochastic-rounding uniforms from an in-register counter hash instead of
-the threefry stream (statistically equivalent, ~10x cheaper on CPU), so
+in repro/kernels/quantize/ uses the same grid and the same rounding rule.
+It draws its stochastic-rounding uniforms from an in-register counter hash
+instead of the threefry stream (statistically equivalent, ~10x cheaper on
+CPU), so it is validated bit for bit against its own jax.numpy oracle,
+`repro.kernels.quantize.ref`, which replays that hash, and, through
+`payload_quantize_dequantize`, against a per-leaf oracle of this module's
+adaptive grid fed the replayed uniforms (tests/test_kernels_quantize.py).
 QDFedRW trajectories of the two engines agree to quantization noise rather
 than bit-for-bit; see tests/test_flat_engine.py.
 """

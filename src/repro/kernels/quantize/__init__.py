@@ -1,15 +1,7 @@
-from repro.kernels.quantize.ops import (
-    payload_quantize_dequantize,
-    segment_quantize_dequantize,
-    stochastic_dequantize,
-    stochastic_quantize,
-)
+from repro.kernels.quantize.ops import payload_quantize_dequantize
 from repro.kernels.quantize import ref
 
 __all__ = [
-    "stochastic_quantize",
-    "stochastic_dequantize",
-    "segment_quantize_dequantize",
     "payload_quantize_dequantize",
     "ref",
 ]

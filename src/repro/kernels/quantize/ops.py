@@ -1,60 +1,19 @@
-"""Jitted public wrappers around the stochastic-quantization Pallas kernel.
+"""Public wrapper of the fused qdq kernel: one flat-buffer payload's
+Eq. 12/13/14 wire round trip in one kernel call.
 
-Handles arbitrary input shapes: flatten -> pad to (k*ROW_TILE, 128) ->
-kernel -> unpad/reshape. `interpret=True` runs the kernel body in Python on
-CPU (this container); on TPU it compiles to a fused VMEM pass.
+`payload_quantize_dequantize` computes each wire tensor's (s, norm) header
+from plain sliced reductions over the 128-aligned leaf column ranges, lays
+the payload out as (rows, 128) lanes and calls `qdq_rows_kernel_call`:
+interpreted on CPU, compiled to one fused VMEM pass on TPU.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.quantize.quantize import (
-    LANES,
-    ROW_TILE,
-    dequantize_kernel_call,
-    qdq_rows_kernel_call,
-    quantize_kernel_call,
-)
+from repro.kernels.quantize.quantize import LANES, ROW_TILE, qdq_rows_kernel_call
 
-__all__ = [
-    "stochastic_quantize",
-    "stochastic_dequantize",
-    "segment_quantize_dequantize",
-    "payload_quantize_dequantize",
-]
-
-_TILE = ROW_TILE * LANES
-
-
-def _pad2d(flat: jax.Array) -> jax.Array:
-    n = flat.shape[0]
-    pad = (-n) % _TILE
-    return jnp.pad(flat, (0, pad)).reshape(-1, LANES)
-
-
-@functools.partial(jax.jit, static_argnames=("s", "bits", "interpret"))
-def stochastic_quantize(w: jax.Array, key: jax.Array, *, s: float, bits: int = 8,
-                        interpret: bool = True):
-    """Quantize tensor w -> (int8 indices, norm). The wire format is
-    (indices, s, norm): 64 + bits*d bits (paper §IV-B)."""
-    flat = w.reshape(-1).astype(jnp.float32)
-    norm = jnp.linalg.norm(flat)
-    u = jax.random.uniform(key, flat.shape, dtype=jnp.float32)
-    q2d = quantize_kernel_call(_pad2d(flat), _pad2d(u), norm, s=s, bits=bits,
-                               interpret=interpret)
-    return q2d.reshape(-1)[: flat.shape[0]].reshape(w.shape), norm
-
-
-@functools.partial(jax.jit, static_argnames=("s", "out_dtype", "interpret"))
-def stochastic_dequantize(q: jax.Array, norm: jax.Array, *, s: float,
-                          out_dtype=jnp.float32, interpret: bool = True):
-    flat = q.reshape(-1)
-    out2d = dequantize_kernel_call(_pad2d(flat).astype(jnp.int8), norm, s=s,
-                                   out_dtype=out_dtype, interpret=interpret)
-    return out2d.reshape(-1)[: flat.shape[0]].reshape(q.shape)
+__all__ = ["payload_quantize_dequantize"]
 
 
 def payload_quantize_dequantize(payload: jax.Array, layout, *, per_message: bool,
@@ -112,6 +71,8 @@ def payload_quantize_dequantize(payload: jax.Array, layout, *, per_message: bool
     w2d = wf.reshape(rows, LANES)
     base2d = None if base is None else base.reshape(rows, LANES)
     if not interpret:
+        # pad rows quantize to 0 (w=0, s=1, norm=0 -> safe norm 1) and are
+        # sliced off after
         pad = (-rows) % ROW_TILE
         if pad:
             w2d = jnp.pad(w2d, ((0, pad), (0, 0)))
@@ -119,74 +80,6 @@ def payload_quantize_dequantize(payload: jax.Array, layout, *, per_message: bool
             norm_rows = jnp.pad(norm_rows, (0, pad))
             if base2d is not None:
                 base2d = jnp.pad(base2d, ((0, pad), (0, 0)))
-        deq = qdq_rows_kernel_call(w2d, None, s_rows, norm_rows, bits=bits,
-                                   base2d=base2d, seed=seed, interpret=False)
-        return deq[: rows].reshape(b, d_pad)
-    deq = qdq_rows_kernel_call(w2d, None, s_rows, norm_rows, bits=bits,
-                               base2d=base2d, seed=seed, interpret=True)
-    return deq.reshape(b, d_pad)
-
-
-def segment_quantize_dequantize(w_rows: jax.Array, u_rows: jax.Array | None,
-                                seg_ids: jax.Array, num_segments: int, *,
-                                bits: int, base_rows: jax.Array | None = None,
-                                key: jax.Array | None = None,
-                                interpret: bool | None = None) -> jax.Array:
-    """Fused wire simulation Q^-1(Q(w)) of one multi-tensor payload (Eq. 12/13).
-
-    ``w_rows``/``u_rows`` are the payload and its pre-drawn uniforms laid out
-    as (R, 128) rows (pass ``u_rows=None`` with a jax PRNG ``key`` to use the
-    kernel's in-register counter RNG instead — the fast protocol path);
-    ``seg_ids`` (R,) assigns every row to one wire tensor
-    (a per-leaf or per-(message, leaf) segment — repro.core.flatten aligns
-    leaves to 128-element rows precisely so this mapping exists). Per segment
-    the paper's adaptive grid is used: norm = ||w_seg||, s = max|w_v| /
-    (||w_seg|| * levels), matching repro.core.quantization.quantize; the
-    quantize -> dequantize round trip then runs as ONE fused Pallas kernel
-    call over the whole payload (`qdq_rows_kernel_call`: the int8 indices
-    stay in registers), instead of a per-leaf Python loop. ``base_rows``
-    additionally fuses the receiver's reconstruction base + deq into the
-    same pass (the hop hand-off w^k + deq(Q(diff))).
-
-    Intended to be called inside jit (the protocol round function); all
-    shapes static, scales dynamic.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    rows = w_rows.shape[0]
-    assert w_rows.shape[1] == LANES, w_rows.shape
-    levels = max((1 << (bits - 1)) - 1, 1)
-    wf = w_rows.astype(jnp.float32)
-    # Segment-wise side information (the (norm, s) wire header per tensor).
-    norm_seg = jnp.sqrt(
-        jax.ops.segment_sum(jnp.sum(wf * wf, axis=1), seg_ids,
-                            num_segments=num_segments)
-    )
-    absmax_seg = jax.ops.segment_max(jnp.max(jnp.abs(wf), axis=1), seg_ids,
-                                     num_segments=num_segments)
-    safe_norm = jnp.where(norm_seg > 0, norm_seg, 1.0)
-    xmax = absmax_seg / safe_norm
-    s_seg = jnp.where(xmax > 0, xmax / levels, 1.0).astype(jnp.float32)
-    s_rows = s_seg[seg_ids]
-    norm_rows = norm_seg[seg_ids]
-    seed = None
-    if u_rows is None:
-        assert key is not None, "pass u_rows or key"
-        seed = jax.random.key_data(key).reshape(-1)[:2]
-    else:
-        u_rows = u_rows.astype(jnp.float32)
-    if interpret:
-        # One whole-payload block; no tile padding needed.
-        return qdq_rows_kernel_call(wf, u_rows, s_rows, norm_rows, bits=bits,
-                                    base2d=base_rows, seed=seed, interpret=True)
-    # Pad the row count to the kernel tile; pad rows quantize to 0 (w=0, u=0,
-    # s=1, norm=0 -> safe norm 1) and are sliced off after.
-    pad = (-rows) % ROW_TILE
-    wp = jnp.pad(wf, ((0, pad), (0, 0)))
-    up = None if u_rows is None else jnp.pad(u_rows, ((0, pad), (0, 0)))
-    sp = jnp.pad(s_rows, (0, pad), constant_values=1.0)
-    np_ = jnp.pad(norm_rows, (0, pad))
-    bp = None if base_rows is None else jnp.pad(base_rows, ((0, pad), (0, 0)))
-    deq = qdq_rows_kernel_call(wp, up, sp, np_, bits=bits, base2d=bp,
-                               seed=seed, interpret=interpret)
-    return deq[:rows]
+    deq = qdq_rows_kernel_call(w2d, s_rows, norm_rows, bits=bits, seed=seed,
+                               base2d=base2d, interpret=interpret)
+    return deq[: rows].reshape(b, d_pad)

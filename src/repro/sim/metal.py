@@ -11,8 +11,8 @@ devices and holding the result to the simulator's trajectory:
     chain walks are sharded over a device mesh (``shard_map`` over a
     ``"chains"`` axis — single-process multi-device is the CI fallback,
     ``launch/replay.py`` adds localhost multi-process on top via an
-    :class:`Exchange`), then a replicated finalize applies the engine's
-    winner-election scatter and Eq. 11/14 aggregation.
+    :class:`Exchange`), then the engine's own finalize runs replicated:
+    winner election, `merge_rows` row writes and Eq. 11/14 aggregation.
   * :class:`FaultInjector` re-derives the executed-step masks and dead
     aggregators from the trace's raw fault timeline (completion timestamps,
     churn kills, straggler deficits) instead of trusting the recorded
@@ -47,17 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dfedrw import (
-    DFedRW,
-    DFedRWState,
-    RoundMetrics,
-    gamma_hat_from_traj,
-)
-from repro.core.flatten import elect_writers, unflatten_tree
+from repro.core.dfedrw import DFedRW, DFedRWState, RoundMetrics
 from repro.core.metrics import History
 from repro.core.walk import WalkPlan
-from repro.kernels.quantize import payload_quantize_dequantize
-from repro.optim.sgd import decreasing_lr
 from repro.sim.trace import (
     TRACE_SHAPE_KEYS,
     SimTrace,
@@ -218,10 +210,11 @@ class MetalReplay:
     """Execute a recorded schedule on live devices.
 
     Wraps a :class:`~repro.core.dfedrw.DFedRW` engine (flat only) for its
-    spec, data binding, Eq. 18 pricing and evaluation — but never calls its
-    round program: the walk phase runs as a ``shard_map`` over a
-    ``"chains"`` mesh axis of this process's devices, and the finalize
-    (winner election + scatter + aggregation) runs replicated, so every
+    spec, data binding, Eq. 18 pricing and evaluation, and runs the two
+    halves of its round program (`DFedRW.round_parts`) apart: the engine's
+    walk as a ``shard_map`` over a ``"chains"`` mesh axis of this process's
+    devices, and the engine's finalize (winner election, `merge_rows`
+    writes, aggregation) replicated on the exchanged trajectory, so every
     shard deterministically computes the same new device matrix.
 
     ``exchange`` splits the M chains across processes
@@ -270,58 +263,29 @@ class MetalReplay:
         return mesh
 
     def _walk_fn(self, bits: int, m_local: int):
-        """Compiled walk program for (wire width, shard chain count): scans
-        the chain SGD steps exactly like the engine's round program —
-        Eq. 10 masked steps at the globally decreasing lr, Eq. 13 quantized
-        hand-offs when bits<32 — over this shard's rows only."""
+        """Compiled walk program for (wire width, shard chain count): the
+        engine's walk (`DFedRW.round_parts`) — Eq. 10 masked steps at the
+        globally decreasing lr, Eq. 13 quantized hand-offs when bits<32 —
+        over this shard's rows only."""
         fn = self._walk_fns.get((bits, m_local))
         if fn is not None:
             return fn
         from jax.sharding import PartitionSpec as P
 
-        engine = self.engine
-        cfg, spec = engine.cfg, engine.flat_spec
-        quant_on = bits < 32
-        model = engine.model
+        walk, _ = self.engine.round_parts(bits)
         mesh = self._local_mesh(m_local)
         sharded = len(mesh.devices) > 1
 
-        def loss_flat(vec, batch):
-            return model.loss_fn(unflatten_tree(vec, spec), batch)
-
-        grad_fn = jax.vmap(jax.grad(loss_flat))
-
         def body(x, y, chain_flat, mask, bidx, kbar0, qkey):
-            if quant_on:
+            if bits < 32:
                 # Distinct stream per mesh position: a valid stochastic
                 # quantizer, a different draw order than the sim — this is
                 # the source of the bits<32 tolerance band.
                 shard_ix = jax.lax.axis_index("chains") if sharded else 0
                 qkey = jax.random.fold_in(qkey, shard_ix)
             bidx_t = jnp.swapaxes(bidx, 0, 1)          # (K, mb, B)
-            xb_all = x[bidx_t]
-            yb_all = y[bidx_t]
-
-            def scan_body(carry, inputs):
-                chain, qk = carry
-                xb, yb, step_k = inputs
-                lr = decreasing_lr(kbar0 + step_k + 1, cfg.lr_r, cfg.lr_q)
-                grads = grad_fn(chain, (xb, yb))
-                mask_k = mask[:, step_k]
-                stepped = jnp.where(
-                    mask_k[:, None], chain - lr * grads, chain)
-                if quant_on:
-                    qk, sub = jax.random.split(qk)
-                    stepped = payload_quantize_dequantize(
-                        stepped - chain, spec, per_message=False, bits=bits,
-                        s=cfg.quant.s, key=sub, base=chain)
-                return (stepped, qk), (stepped,
-                                       jnp.sum(grads * grads, axis=1))
-
-            steps = jnp.arange(mask.shape[1], dtype=jnp.int32)
-            (_, _), (traj, grad_sq) = jax.lax.scan(
-                scan_body, (chain_flat, qkey), (xb_all, yb_all, steps),
-                unroll=True)
+            _, (traj, grad_sq) = walk(
+                chain_flat, (x[bidx_t], y[bidx_t]), mask, kbar0, qkey)
             return traj, grad_sq                       # (K, mb, d) / (K, mb)
 
         if sharded:
@@ -337,65 +301,15 @@ class MetalReplay:
         return fn
 
     def _finalize_fn(self, bits: int):
-        """Replicated finalize: the engine's w^{t,last} winner-election
-        scatter and Eq. 11 / Eq. 14 aggregation, verbatim, over the full
-        gathered (K, M, d) trajectory — byte-for-byte the same graph as the
-        tail of ``DFedRW._build_round_fn_flat``, which is what makes metal
-        bit-exact at fp32 regardless of how the walk was sharded."""
+        """Replicated finalize: the engine's own finalize (winner election,
+        `merge_rows` writes, Eq. 11 / Eq. 14 aggregation, loss) over the
+        full gathered (K, M, d) trajectory — the ops of the tail of
+        ``DFedRW.round_program``, which is what makes metal bit-exact at
+        fp32 regardless of how the walk was sharded."""
         fn = self._finalize_fns.get(bits)
-        if fn is not None:
-            return fn
-        engine = self.engine
-        cfg, spec = engine.cfg, engine.flat_spec
-        quant_on = bits < 32
-        model = engine.model
-
-        def loss_flat(vec, batch):
-            return model.loss_fn(unflatten_tree(vec, spec), batch)
-
-        @jax.jit
-        def finalize(device_flat, traj, grad_sq, walk_devices, walk_mask,
-                     agg_rows, agg_weights, agg_devices, last_bidx, qkey):
-            x, y = engine._x, engine._y
-            k, m, d_pad = traj.shape
-            n_dev = device_flat.shape[0]
-            traj2 = traj.reshape(k * m, d_pad)
-            devs_flat = walk_devices.T.reshape(-1)     # step-major
-            mask_flat = walk_mask.T.reshape(-1)
-            _, wins = elect_writers(devs_flat, mask_flat, n_dev)
-            loser_oob = n_dev + jnp.arange(k * m, dtype=devs_flat.dtype)
-            dev_last = device_flat.at[
-                jnp.where(wins, devs_flat, loser_oob)
-            ].set(traj2, mode="drop", unique_indices=True)
-
-            gamma_hat = gamma_hat_from_traj(grad_sq, walk_mask)
-
-            if quant_on:
-                base_rows = device_flat[devs_flat]
-                diffs = jnp.where(wins[:, None], traj2 - base_rows, 0.0)
-                deq = payload_quantize_dequantize(
-                    diffs, spec, per_message=True, bits=bits,
-                    s=cfg.quant.s, key=qkey)
-                hits = agg_rows[:, :, None] == devs_flat[None, None, :]
-                w3 = (jnp.sum(agg_weights[:, :, None] * hits, axis=1)
-                      * wins[None, :].astype(jnp.float32))
-                upd = w3 @ deq
-                base = device_flat[agg_devices]
-                new_device_flat = dev_last.at[agg_devices].set(
-                    base + upd, mode="drop", unique_indices=True)
-            else:
-                gathered = dev_last[agg_rows]
-                avg = jnp.sum(agg_weights[..., None] * gathered, axis=1)
-                new_device_flat = dev_last.at[agg_devices].set(
-                    avg, mode="drop", unique_indices=True)
-
-            chain_final = traj[-1]                     # scan's final carry
-            losses = jax.vmap(loss_flat)(
-                chain_final, (x[last_bidx], y[last_bidx]))
-            return new_device_flat, jnp.mean(losses), gamma_hat
-
-        self._finalize_fns[bits] = finalize
-        return fn if fn is not None else finalize
+        if fn is None:
+            fn = self._finalize_fns[bits] = jax.jit(self.engine.round_parts(bits)[1])
+        return fn
 
     # ----------------------------------------------------------- execution
     def _check_trace(self, trace: SimTrace) -> None:
@@ -452,12 +366,14 @@ class MetalReplay:
                 f"exchange returned {traj.shape[1]} chains, schedule has {m}")
 
         agg_key = jax.random.fold_in(sub, 4096)  # off the shard-key range
+        last_bidx = jnp.asarray(w.bidx[:, -1])
         finalize = self._finalize_fn(w.bits)
         new_params, loss, gamma_hat = finalize(
             state.device_params, traj, grad_sq,
             jnp.asarray(w.devices), jnp.asarray(exec_mask),
             jnp.asarray(w.agg_rows), jnp.asarray(w.agg_weights),
-            jnp.asarray(w.agg_devices), jnp.asarray(w.bidx[:, -1]), agg_key)
+            jnp.asarray(w.agg_devices), agg_key,
+            traj[-1], (engine._x[last_bidx], engine._y[last_bidx]))
 
         account_plan = WalkPlan(
             devices=w.devices, mask=w.account_mask,

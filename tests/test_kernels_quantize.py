@@ -1,21 +1,17 @@
-"""Pallas stochastic-quantization kernel vs pure-jnp oracle: shape/dtype/bits
-sweep in interpret mode (kernel body executes on CPU), plus the per-row
-segment variants and the in-kernel counter RNG used by the flat round
-engine."""
+"""The fused qdq Pallas kernel the round runs, against its plain jax.numpy
+oracle (ref.py) in interpret mode (the kernel body executes on CPU): bit
+for bit over a shape/dtype/bits sweep, with the fused receiver base, and
+through `payload_quantize_dequantize` against a per-leaf oracle; plus the
+wire laws of the payload path (one grid cell, fixed interval, counter RNG)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.flatten import make_flat_spec
-from repro.core.quantization import QuantConfig, dequantize, quantize
-from repro.kernels.quantize import (
-    payload_quantize_dequantize,
-    segment_quantize_dequantize,
-    stochastic_dequantize,
-    stochastic_quantize,
-)
-from repro.kernels.quantize.ref import dequantize_ref, quantize_ref
+from repro.kernels.quantize import payload_quantize_dequantize
+from repro.kernels.quantize.quantize import LANES, qdq_rows_kernel_call
+from repro.kernels.quantize.ref import counter_uniforms, qdq_rows_ref
 from repro.models import make_fnn
 
 SHAPES = [(64,), (1000,), (128, 128), (64, 129), (3, 5, 7), (65536,), (2048, 33)]
@@ -23,50 +19,67 @@ DTYPES = [jnp.float32, jnp.bfloat16]
 BITS = [4, 8]
 
 
+def _seed(key):
+    return jax.random.key_data(key).reshape(-1)[:2]
+
+
+def _one_tensor_rows(shape, dtype, bits, key):
+    """One wire tensor laid out in 128-lane rows as the flat layout pads
+    it, rounded through ``dtype``, with its adaptive (s, norm) per row."""
+    w = (jax.random.normal(key, shape, jnp.float32) * 2.3).astype(dtype)
+    flat = w.reshape(-1).astype(jnp.float32)
+    w2d = jnp.pad(flat, (0, (-flat.size) % LANES)).reshape(-1, LANES)
+    rows = w2d.shape[0]
+    levels = (1 << (bits - 1)) - 1
+    norm = jnp.sqrt(jnp.sum(flat * flat))
+    s = jnp.max(jnp.abs(flat)) / norm / levels
+    return w2d, jnp.full((rows,), s), jnp.full((rows,), norm)
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("bits", BITS)
-def test_kernel_matches_oracle(shape, dtype, bits):
+def test_qdq_kernel_matches_oracle(shape, dtype, bits):
     key = jax.random.PRNGKey(hash((shape, bits)) % (2**31))
-    w = (jax.random.normal(key, shape, jnp.float32) * 2.3).astype(dtype)
-    s = 1.0 / ((1 << (bits - 1)) - 1)
-    q, norm = stochastic_quantize(w, key, s=s, bits=bits, interpret=True)
-    flat = w.reshape(-1).astype(jnp.float32)
-    u = jax.random.uniform(key, flat.shape, dtype=jnp.float32)
-    q_ref = quantize_ref(flat, u, norm, s=s, bits=bits).reshape(shape)
-    np.testing.assert_array_equal(np.asarray(q), np.asarray(q_ref))
-
-    deq = stochastic_dequantize(q, norm, s=s, interpret=True)
-    deq_ref = dequantize_ref(q_ref, norm, s=s)
-    np.testing.assert_allclose(np.asarray(deq), np.asarray(deq_ref), rtol=1e-6)
+    w2d, s_rows, norm_rows = _one_tensor_rows(shape, dtype, bits, key)
+    got = qdq_rows_kernel_call(w2d, s_rows, norm_rows, bits=bits, seed=_seed(key),
+                               interpret=True)
+    want = qdq_rows_ref(w2d, s_rows, norm_rows, bits=bits, seed=_seed(key))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the layout's padding lanes carry zero and stay zero
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1)[int(np.prod(shape)):], 0.0)
 
 
 @pytest.mark.parametrize("bits", BITS)
-def test_kernel_error_bound(bits):
+def test_qdq_kernel_error_bound(bits):
     """Reconstruction error within one grid cell: |deq - w| <= s * ||w||."""
     key = jax.random.PRNGKey(7)
-    w = jax.random.normal(key, (4096,)) * 10.0
-    s = 1.0 / ((1 << (bits - 1)) - 1)
-    q, norm = stochastic_quantize(w, key, s=s, bits=bits, interpret=True)
-    deq = stochastic_dequantize(q, norm, s=s, interpret=True)
-    assert float(jnp.abs(deq - w).max()) <= s * float(norm) * (1 + 1e-5)
+    w2d, s_rows, norm_rows = _one_tensor_rows((4096,), jnp.float32, bits, key)
+    w2d = w2d * 10.0
+    norm_rows = norm_rows * 10.0
+    deq = qdq_rows_kernel_call(w2d, s_rows, norm_rows, bits=bits, seed=_seed(key),
+                               interpret=True)
+    cell = float(s_rows[0] * norm_rows[0])
+    assert float(jnp.abs(deq - w2d).max()) <= cell * (1 + 1e-5)
 
 
-def test_kernel_unbiased_statistically():
-    key = jax.random.PRNGKey(9)
-    w = jax.random.normal(key, (512,))
-    s = 1.0 / 127
-    acc = jnp.zeros_like(w)
-    n = 100
-    for i in range(n):
-        q, norm = stochastic_quantize(w, jax.random.PRNGKey(i), s=s, bits=8, interpret=True)
-        acc = acc + stochastic_dequantize(q, norm, s=s, interpret=True)
-    bias = jnp.abs(acc / n - w).max()
-    norm = float(jnp.linalg.norm(w))
-    assert float(bias) < 5.0 * s * norm / 2.0 / np.sqrt(n)
+def test_qdq_kernel_base_fusion_matches_oracle():
+    """out = base + Q^-1(Q(w)) at a row count that is NOT a multiple of
+    ROW_TILE (the single-block interpret path)."""
+    rng = np.random.default_rng(2)
+    rows = 37
+    w = jnp.asarray(rng.normal(size=(rows, LANES)).astype(np.float32) * 0.1)
+    base = jnp.asarray(rng.normal(size=(rows, LANES)).astype(np.float32))
+    s_rows = jnp.asarray(rng.uniform(1e-4, 1e-2, rows).astype(np.float32))
+    n_rows = jnp.asarray(rng.uniform(0.5, 3.0, rows).astype(np.float32))
+    seed = jnp.asarray([12345, 678], jnp.uint32)
+    got = qdq_rows_kernel_call(w, s_rows, n_rows, bits=8, seed=seed, base2d=base,
+                               interpret=True)
+    want = qdq_rows_ref(w, s_rows, n_rows, bits=8, seed=seed, base2d=base)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-# ------------------------------------------------ segment / payload variants
+# ------------------------------------------------------------ payload path
 
 
 def _model_payload(b, seed=0, scale=0.05):
@@ -81,37 +94,32 @@ def _model_payload(b, seed=0, scale=0.05):
     return spec, flat * jnp.asarray(mask)
 
 
-def test_segment_qdq_matches_per_leaf_oracle_given_same_uniforms():
-    """With explicit uniforms, the fused segment pass is (numerically) the
-    per-leaf reference: one wire tensor per leaf spanning all rows."""
-    from repro.core.flatten import LANES, flatten_tree, unflatten_tree
-
-    spec, flat = _model_payload(3)
+@pytest.mark.parametrize("per_message", [False, True])
+def test_payload_qdq_matches_per_leaf_oracle(per_message):
+    """A three-message payload through the fused path equals the paper's
+    per-leaf wire round trip (adaptive grid per wire tensor: per message
+    and leaf at Eq. 14, per leaf across messages at Eq. 13) fed the
+    kernel's replayed counter uniforms."""
+    spec, flat = _model_payload(3, seed=4)
     key = jax.random.PRNGKey(42)
-    keys = jax.random.split(key, spec.n_leaves)
-    cfg = QuantConfig(bits=8)
-    tree = unflatten_tree(flat, spec)
-    oracle_leaves = [
-        dequantize(quantize(leaf, cfg, k), dtype=leaf.dtype)
-        for leaf, k in zip(jax.tree_util.tree_leaves(tree), keys)
-    ]
-    oracle = flatten_tree(
-        jax.tree_util.tree_unflatten(spec.treedef, oracle_leaves), spec
-    )
-    # matching uniforms: same per-leaf draws, padded into the flat layout
-    segs = []
-    for l in range(spec.n_leaves):
-        u = jax.random.uniform(keys[l], (3, spec.sizes[l]), dtype=jnp.float32)
-        segs.append(jnp.pad(u, ((0, 0), (0, spec.padded_sizes[l] - spec.sizes[l]))))
-    u_flat = jnp.concatenate(segs, axis=1)
-    rows = 3 * spec.rows
-    seg_ids = jnp.asarray(np.tile(spec.row_leaf_ids(), 3))
-    got = segment_quantize_dequantize(
-        flat.reshape(rows, LANES), u_flat.reshape(rows, LANES),
-        seg_ids, spec.n_leaves, bits=8,
-    ).reshape(3, spec.d_pad)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(oracle),
-                               atol=1e-6, rtol=1e-5)
+    bits, levels = 8, 127
+    got = payload_quantize_dequantize(flat, spec, per_message=per_message, bits=bits,
+                                      key=key)
+    u = counter_uniforms(3 * spec.rows, _seed(key)).reshape(3, spec.d_pad)
+    leaves = []
+    for off, psize in zip(spec.offsets, spec.padded_sizes):
+        w, u_l = flat[:, off:off + psize], u[:, off:off + psize]
+        axis = 1 if per_message else None
+        norm = jnp.sqrt(jnp.sum(w * w, axis=axis, keepdims=True))
+        safe = jnp.where(norm > 0, norm, 1.0)
+        xmax = jnp.max(jnp.abs(w), axis=axis, keepdims=True) / safe
+        s = jnp.where(xmax > 0, xmax / levels, 1.0)
+        x = jnp.abs(w) / safe
+        ell = jnp.floor(x / s)
+        idx = jnp.clip(ell + (u_l < x / s - ell), 0, levels)
+        leaves.append(idx * jnp.sign(w) * s * norm)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.concatenate(leaves, axis=1)))
 
 
 @pytest.mark.parametrize("per_message", [False, True])
@@ -169,30 +177,6 @@ def test_payload_qdq_base_fusion():
     fused = payload_quantize_dequantize(flat, spec, per_message=True, bits=8,
                                         key=key, base=base)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(base + plain),
-                               rtol=1e-6, atol=1e-7)
-
-
-def test_rows_wire_kernels_match_fused_qdq():
-    """The int8 wire kernels (quantize_rows -> dequantize_rows) reproduce the
-    fused qdq round trip given the same uniforms, including at a row count
-    that is NOT a multiple of ROW_TILE (single-block interpret path)."""
-    from repro.kernels.quantize.quantize import (
-        dequantize_rows_kernel_call,
-        qdq_rows_kernel_call,
-        quantize_rows_kernel_call,
-    )
-
-    rng = np.random.default_rng(2)
-    rows = 37  # deliberately not a ROW_TILE multiple
-    w = jnp.asarray(rng.normal(size=(rows, 128)).astype(np.float32) * 0.1)
-    u = jnp.asarray(rng.random(size=(rows, 128)).astype(np.float32))
-    s_rows = jnp.asarray(rng.uniform(1e-4, 1e-2, rows).astype(np.float32))
-    n_rows = jnp.asarray(rng.uniform(0.5, 3.0, rows).astype(np.float32))
-    q = quantize_rows_kernel_call(w, u, s_rows, n_rows, bits=8, interpret=True)
-    assert q.dtype == jnp.int8 and (np.abs(np.asarray(q)) <= 127).all()
-    deq = dequantize_rows_kernel_call(q, s_rows, n_rows, interpret=True)
-    fused = qdq_rows_kernel_call(w, u, s_rows, n_rows, bits=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(deq), np.asarray(fused),
                                rtol=1e-6, atol=1e-7)
 
 

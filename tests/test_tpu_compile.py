@@ -74,17 +74,20 @@ def test_payload_qdq_compiles_to_kernel(one_chip, fnn_spec, bits, with_base):
     assert "tpu_custom_call" in _hlo(qdq, *args)
 
 
-def test_qdq_rows_kernel_with_drawn_uniforms_compiles(one_chip):
-    from repro.kernels.quantize.quantize import ROW_TILE, qdq_rows_kernel_call
+def test_hop_qdq_compiles_to_kernel(one_chip, fnn_spec):
+    """The walk's Eq. 13 hop hand-off: one wire tensor per leaf across the
+    M=10 chain rows (``per_message=False``), with the receiver's base
+    fused."""
+    from repro.kernels.quantize import payload_quantize_dequantize
 
-    rows = 4 * ROW_TILE
-    w = jax.ShapeDtypeStruct((rows, 128), jnp.float32, sharding=one_chip)
-    per_row = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one_chip)
+    chains = jax.ShapeDtypeStruct((10, fnn_spec.d_pad), jnp.float32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
 
-    def qdq(w2d, u2d, s_rows, norm_rows):
-        return qdq_rows_kernel_call(w2d, u2d, s_rows, norm_rows, bits=8)
+    def hop(diff, k, base):
+        return payload_quantize_dequantize(diff, fnn_spec, per_message=False, bits=8,
+                                           key=k, base=base, interpret=False)
 
-    assert "tpu_custom_call" in _hlo(qdq, w, w, per_row, per_row)
+    assert "tpu_custom_call" in _hlo(hop, chains, key, chains)
 
 
 _PERMUTE_RE = re.compile(
